@@ -89,16 +89,10 @@ from repro.core.angles import _DEFAULT_BLOCK, proximity_matrix
 
 KS = (128, 512, 2048)
 MEASURES = ("eq2", "eq3")
-# block_size=None: each backend's tuned default (blocked: 64 eq3 / 96 eq2,
-# sharded: 64) — what PACFLConfig.proximity_block=None also uses.  The
-# pallas kernel gets a large tile instead: off-TPU it runs in interpret
-# mode, where its tuned bk=8 would mean O(K^2/64) Python-level grid steps.
+# block_size=None: each backend's default (blocked: 64 eq3 / 96 eq2,
+# sharded: 64, pallas: 128) — what PACFLConfig.proximity_block=None also
+# uses.
 BLOCK_SIZE = None
-PALLAS_BLOCK = 64
-
-
-def _block_for(backend):
-    return PALLAS_BLOCK if backend == "pallas" else BLOCK_SIZE
 # The dense path's (K, K, p, p) tensor passes ~400 MB at K=2048; keep the
 # reference to sizes where it is the sensible baseline.
 DENSE_MAX_K = 512
@@ -198,6 +192,8 @@ def _sharded_multi_device(K: int, ndev: int) -> dict:
         + f" --xla_force_host_platform_device_count={ndev}"
     ).strip()
     env["PYTHONPATH"] = str(ROOT / "src")
+    # A virtual-device rehearsal, and the parent already holds the chip.
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, "-c", _SHARDED_SCRIPT, str(K)],
         capture_output=True, text=True, env=env, timeout=1800,
@@ -219,7 +215,7 @@ def _parity_rows(record, rows):
     for measure, backend, solver in checks:
         got = np.asarray(
             proximity_matrix(
-                U, measure, backend=backend, block_size=_block_for(backend),
+                U, measure, backend=backend, block_size=BLOCK_SIZE,
                 eq2_solver=solver,
             )
         )
@@ -517,6 +513,8 @@ def _memory_rows(record, rows, Ks=MEMORY_KS, policies=MEMORY_POLICIES):
         )
         env = dict(os.environ)
         env["PYTHONPATH"] = str(ROOT / "src")
+        # No device work by design, and the parent already holds the chip.
+        env["JAX_PLATFORMS"] = "cpu"
         for K in Ks:
             per_k = []
             for mode in policies:
@@ -1151,7 +1149,7 @@ def run(quick: bool = True, parity_only: bool = False):
     rows = []
     record = {
         "jax_backend": jax.default_backend(),
-        "block_size": {**_DEFAULT_BLOCK, "pallas": PALLAS_BLOCK},
+        "block_size": _DEFAULT_BLOCK,
         "parity_tol_deg": PARITY_TOL_DEG,
         "eq2_solver_by_backend": _EQ2_SOLVER,
         "sweep": [],
@@ -1182,7 +1180,7 @@ def run(quick: bool = True, parity_only: bool = False):
             fns = {}
             for measure, backend in combos:
                 fn = lambda measure=measure, backend=backend: proximity_matrix(
-                    U, measure, backend=backend, block_size=_block_for(backend)
+                    U, measure, backend=backend, block_size=BLOCK_SIZE
                 )
                 jax.block_until_ready(fn())  # warmup/compile
                 fns[(measure, backend)] = fn

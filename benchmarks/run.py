@@ -21,6 +21,10 @@ def main() -> None:
     args = ap.parse_args()
     quick = not args.full
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from benchmarks import (
         fig2_beta_sweep,
         kernels_bench,

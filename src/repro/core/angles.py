@@ -50,7 +50,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.measures import EQ2_SOLVERS, measure_from_gram, measure_pair
@@ -127,9 +126,8 @@ def _proximity_blocked(
         i, j = idx
         Ui = jnp.take(blocks, i, axis=0)
         Uj = jnp.take(blocks, j, axis=0)
-        # einsum Gram + shared reduction: on CPU the einsum beats the
-        # kernel-style flat matmul inside the scan (better MKL dispatch);
-        # eq3 only contracts the p Gram diagonals (see measure_pair)
+        # einsum Gram + shared reduction; eq3 only contracts the p Gram
+        # diagonals (see measure_pair)
         tile = measure_pair(Ui, Uj, measure, eq2_solver=eq2_solver)  # (bk, bk)
         A = jax.lax.dynamic_update_slice(A, tile.T, (j * bk, i * bk))
         A = jax.lax.dynamic_update_slice(A, tile, (i * bk, j * bk))
@@ -171,13 +169,15 @@ def _strip_blocks(rows: jax.Array, full: jax.Array, measure, bk, eq2_solver):
 
 @functools.lru_cache(maxsize=None)
 def _sharded_cross_fn(ndev: int, measure: str, bk: int, eq2_solver: str):
-    mesh = jax.make_mesh((ndev,), ("i",))
+    # Auto axes (make_mesh defaults to Explicit): the row-sharded strips are
+    # then a plain GSPMD array, and the hygiene pass's A + A.T may reshard.
+    mesh = jax.make_mesh((ndev,), ("i",), axis_types=(jax.sharding.AxisType.Auto,))
 
     def local(rows, full):
         return _strip_blocks(rows, full, measure, bk, eq2_solver)
 
     return jax.jit(
-        shard_map(local, mesh=mesh, in_specs=(P("i"), P()), out_specs=P("i"))
+        jax.shard_map(local, mesh=mesh, in_specs=(P("i"), P()), out_specs=P("i"))
     )
 
 
@@ -218,7 +218,10 @@ def _cross_sharded(
     return C[:Ka, :Kb]
 
 
-def _resolve_backend(backend: str, K: int) -> str:
+def resolve_backend(backend: str, K: int) -> str:
+    """The backend ``proximity_matrix`` runs for ``backend`` at K clients:
+    ``"auto"`` is pallas on a TPU, else dense below 512 clients and blocked
+    from there; any other name is returned as given."""
     if backend not in PROXIMITY_BACKENDS:
         raise ValueError(
             f"unknown proximity backend: {backend!r} (want one of {PROXIMITY_BACKENDS})"
@@ -234,13 +237,13 @@ def _resolve_backend(backend: str, K: int) -> str:
 # tiles — and eq2's per-tile arithmetic (the packed Jacobi) is heavy enough
 # that a larger tile wins again over the scan overhead, so the blocked
 # default is measure-aware.  The sharded default stays at 64 so the row pad
-# (a multiple of bk * ndev) stays small, and the Pallas kernel's tuned edge
-# is small (VMEM slabs + K padded to a multiple of bk).  An explicit
-# block_size overrides all of these.
+# (a multiple of bk * ndev) stays small.  The Pallas kernel's edge is the
+# smallest its (bk, bk) output block may have on a TPU (the (8, 128) tiling
+# rule), not a tuned value.  An explicit block_size overrides all of these.
 _DEFAULT_BLOCK = {
     "jnp_blocked": {"eq3": 64, "eq2": 96},
     "jnp_sharded": {"eq3": 64, "eq2": 64},
-    "pallas": {"eq3": 8, "eq2": 8},
+    "pallas": {"eq3": 128, "eq2": 128},  # = repro.kernels.proximity BLOCK
 }
 
 # Per-backend eq2 default: the dense reference keeps the LAPACK svd so it
@@ -288,7 +291,7 @@ def proximity_matrix(
         reference at small K and the blocked path beyond.
     block_size: client tile edge for the blocked/sharded/pallas paths; None
         (default) picks the backend's tuned default (blocked: 64 eq3 /
-        96 eq2, sharded: 64, pallas: 8).
+        96 eq2, sharded: 64, pallas: 128).
     eq2_solver: "auto" (default) | "jacobi" | "eigh" | "svd" —
         largest-singular-value solver for eq2 (see repro.core.measures).
         "auto" keeps the dense reference on svd and the scalable paths on
@@ -302,7 +305,7 @@ def proximity_matrix(
     """
     if measure not in ("eq2", "eq3"):
         raise ValueError(f"unknown measure: {measure!r}")
-    resolved = _resolve_backend(backend, int(U_stack.shape[0]))
+    resolved = resolve_backend(backend, int(U_stack.shape[0]))
     solver = _resolve_eq2_solver(eq2_solver, resolved)
     if resolved == "jnp":
         return _proximity_dense(U_stack, measure, solver)
@@ -369,7 +372,7 @@ def cross_proximity(
         raise ValueError(f"unknown measure: {measure!r}")
     # auto must consider BOTH sides: the dense path materializes a
     # (Ka, Kb, p, p) tensor, so a small Ka with a huge Kb still blows up.
-    resolved = _resolve_backend(backend, max(int(U_a.shape[0]), int(U_b.shape[0])))
+    resolved = resolve_backend(backend, max(int(U_a.shape[0]), int(U_b.shape[0])))
     if resolved == "pallas":
         # square-only kernel: the blocked path executes instead, so solver
         # validation and the block default must follow the actual executor

@@ -2,10 +2,12 @@
 
 One implementation used by every proximity backend — the dense einsum
 reference, the blocked ``lax.map`` path and the device-sharded engine in
-``repro.core.angles``, and the Pallas TPU kernel in
-``repro.kernels.proximity`` all reduce their ``(..., p, p)`` Gram blocks
-through :func:`measure_from_gram`, so backends cannot drift apart
-numerically.
+``repro.core.angles`` reduce their ``(..., p, p)`` Gram blocks through
+:func:`measure_from_gram`, and the Pallas TPU kernel in
+``repro.kernels.proximity`` through :func:`measure_tile`, which forms the
+same Gram entries and shares the Jacobi rotations, so backends cannot
+drift apart numerically (the kernel's arccos is the polynomial
+:func:`arccos01`).  Every Gram contraction runs at :data:`GRAM_PRECISION`.
 
 Eq. 3 is a diagonal gather.  Eq. 2 needs the largest singular value of each
 ``p x p`` block ``G = U_i^T U_j`` and dispatches across three solvers:
@@ -32,6 +34,19 @@ import jax
 import jax.numpy as jnp
 
 EQ2_SOLVERS = ("jacobi", "eigh", "svd")
+
+# Precision of every Gram contraction in the core.  On a TPU, XLA's default
+# for an f32 dot is a single bf16 pass, whose ~3 significant digits put
+# angles tenths of a degree off the float64 truth; HIGHEST keeps the
+# products f32 there.  CPU dots are f32 either way.
+GRAM_PRECISION = jax.lax.Precision.HIGHEST
+
+# Abramowitz & Stegun 4.4.46: arccos(x) = sqrt(1 - x) * poly7(x) on [0, 1],
+# |error| <= 2e-8 rad in exact arithmetic (about 2.5e-7 rad in f32).
+_ACOS_COEFFS = (
+    1.5707963050, -0.2145988016, 0.0889789874, -0.0501743046,
+    0.0308918810, -0.0170881256, 0.0066700901, -0.0012624911,
+)
 
 # Cyclic Jacobi sweeps.  Convergence is quadratic; for the paper's p <= 5
 # four sweeps already sit on the f32 roundoff floor (~2e-4 deg worst case on
@@ -129,10 +144,31 @@ def _eq2_jacobi(G: jax.Array) -> jax.Array:
     return jnp.sqrt(jnp.clip(lam, 0.0, None))
 
 
+def arccos01(x: jax.Array) -> jax.Array:
+    """``arccos`` on ``[0, 1]`` in radians, as multiply-adds and one sqrt.
+
+    Pallas has no TPU lowering for ``acos`` (nor ``atan2``), so the
+    kernel's tile uses this form; callers clip into the domain first.  The
+    jnp backends keep ``jnp.arccos``: fused into a Gram contraction on
+    XLA:CPU, this polynomial rounds differently with the array shape, so a
+    pair's angle in an admission's (B, B) square block would less often
+    match its bits in a later (M, B) cross block, and the store would then
+    depend on how churn was batched.
+    """
+    poly = _ACOS_COEFFS[-1]
+    for c in reversed(_ACOS_COEFFS[:-1]):
+        poly = poly * x + c
+    return jnp.sqrt(1.0 - x) * poly
+
+
+def _angle_deg(cos: jax.Array, acos=jnp.arccos) -> jax.Array:
+    """Degrees of ``acos(clip(cos, 0, 1))``."""
+    return jnp.degrees(acos(jnp.clip(cos, 0.0, 1.0)))
+
+
 def eq3_from_diag(d: jax.Array) -> jax.Array:
     """Eq. 3 reduction from ``(..., p)`` Gram *diagonal* entries, degrees."""
-    d = jnp.clip(jnp.abs(d), 0.0, 1.0)
-    return jnp.sum(jnp.degrees(jnp.arccos(d)), axis=-1)
+    return jnp.sum(_angle_deg(jnp.abs(d)), axis=-1)
 
 
 def measure_pair(
@@ -155,8 +191,10 @@ def measure_pair(
     Ui = Ui.astype(jnp.float32)
     Uj = Uj.astype(jnp.float32)
     if measure == "eq3":
-        return eq3_from_diag(jnp.einsum("anr,bnr->abr", Ui, Uj))
-    G = jnp.einsum("anp,bnq->abpq", Ui, Uj)
+        return eq3_from_diag(
+            jnp.einsum("anr,bnr->abr", Ui, Uj, precision=GRAM_PRECISION)
+        )
+    G = jnp.einsum("anp,bnq->abpq", Ui, Uj, precision=GRAM_PRECISION)
     return measure_from_gram(G, measure, eq2_solver=eq2_solver)
 
 
@@ -181,7 +219,7 @@ def measure_from_gram(
     if eq2_solver == "jacobi":
         smax = _eq2_jacobi(G)
     elif eq2_solver == "eigh":
-        B = jnp.swapaxes(G, -1, -2) @ G
+        B = jnp.matmul(jnp.swapaxes(G, -1, -2), G, precision=GRAM_PRECISION)
         smax = jnp.sqrt(jnp.clip(jnp.linalg.eigvalsh(B)[..., -1], 0.0, None))
     elif eq2_solver == "svd":
         s = jnp.linalg.svd(G, compute_uv=False)
@@ -190,48 +228,39 @@ def measure_from_gram(
         raise ValueError(
             f"unknown eq2 solver: {eq2_solver!r} (want one of {EQ2_SOLVERS})"
         )
-    return jnp.degrees(jnp.arccos(jnp.clip(smax, 0.0, 1.0)))
+    return _angle_deg(smax)
 
 
-def measure_tile(
-    Ui: jax.Array, Uj: jax.Array, measure: str, *, eq2_solver: str = "jacobi"
-) -> jax.Array:
-    """Pairwise tile: (bi, n, p) x (bj, n, p) signatures -> (bi, bj) degrees.
+def measure_tile(Ui, Uj, measure: str) -> jax.Array:
+    """Pallas kernel tile: p column slabs per side -> ``(bi, bj)`` degrees.
 
-    The Pallas kernel's tile: one flat matmul ``(bi*p, n) @ (n, bj*p)``
-    forms every pairwise Gram block at once — the MXU shape on TPU — and
-    both measures then reduce static slices of the flat ``(bi, p, bj, p)``
-    layout directly: eq3 gathers the ``p`` Gram diagonals, the Jacobi eq2
-    builds its packed ``B = G^T G`` entries without ever materializing the
-    ``(bi, bj, p, p)`` transpose.  The jnp blocked/sharded backends keep an
-    einsum Gram (faster under XLA CPU's scan) but share the identical
-    rotation/arccos reduction code below, so backends can differ only by
-    float reduction order, never by algorithm.  Everything here lowers
-    inside the Pallas kernel except the LAPACK eq2 fallbacks, which
-    transpose and defer to :func:`measure_from_gram`.
+    ``Ui[r]`` is the ``(bi, n)`` slab of column ``r`` of ``bi`` signatures
+    (the kernel's ``(p, K, n)`` layout), likewise ``Uj[r]`` with ``bj``.
+    Each Gram entry ``G_ab[r, q] = <Ui[r][a], Uj[q][b]>`` comes from one
+    plain 2-D ``(bi, n) . (n, bj)`` MXU matmul: p of them for eq3, which
+    needs only the diagonal, and p^2 for eq2.  The reductions are then
+    elementwise over ``(bi, bj)`` planes: eq3 sums the p arccos terms, and
+    eq2 builds the packed ``B = G^T G`` entries for the Jacobi eigensolve.
+    No reshape, transpose or gather, so Mosaic lowers all of it.  The Jacobi
+    rotations are the code every jnp backend reduces with; the arccos is
+    :func:`arccos01`, within 2.5e-7 rad of theirs.
     """
-    bi, n, p = Ui.shape
-    bj = Uj.shape[0]
-    uif = Ui.transpose(0, 2, 1).reshape(bi * p, n)
-    ujf = Uj.transpose(0, 2, 1).reshape(bj * p, n)
-    M = jax.lax.dot_general(
-        uif, ujf, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    M4 = M.reshape(bi, p, bj, p)  # [a, r, b, q] = G_ab[r, q]
+    p = len(Ui)
+
+    def gram(r, q):
+        return jax.lax.dot_general(
+            Ui[r], Uj[q], (((1,), (1,)), ((), ())),
+            precision=GRAM_PRECISION, preferred_element_type=jnp.float32,
+        )
+
     if measure == "eq3":
-        total = None
-        for r in range(p):
-            drr = jnp.clip(jnp.abs(M4[:, r, :, r]), 0.0, 1.0)
-            ang = jnp.degrees(jnp.arccos(drr))
-            total = ang if total is None else total + ang
+        total = _angle_deg(jnp.abs(gram(0, 0)), arccos01)
+        for r in range(1, p):
+            total = total + _angle_deg(jnp.abs(gram(r, r)), arccos01)
         return total
     if measure != "eq2":
         raise ValueError(f"unknown measure: {measure!r}")
-    if eq2_solver != "jacobi":
-        return measure_from_gram(
-            M4.transpose(0, 2, 1, 3), measure, eq2_solver=eq2_solver
-        )
-    S = [[M4[:, k, :, q] for q in range(p)] for k in range(p)]
+    S = [[gram(k, q) for q in range(p)] for k in range(p)]
     b = {}
     for q in range(p):
         for r in range(q, p):
@@ -240,5 +269,4 @@ def measure_tile(
                 acc = acc + S[k][q] * S[k][r]
             b[(q, r)] = acc
     lam = jacobi_max_eig_packed(b, p)
-    smax = jnp.sqrt(jnp.clip(lam, 0.0, None))
-    return jnp.degrees(jnp.arccos(jnp.clip(smax, 0.0, 1.0)))
+    return _angle_deg(jnp.sqrt(jnp.clip(lam, 0.0, None)), arccos01)

@@ -65,7 +65,7 @@ class PACFLConfig:
     proximity_backend: str = "auto"
     # Client tile edge for the blocked/sharded/pallas paths; None picks the
     # backend's tuned default (blocked: 64 eq3 / 96 eq2; sharded: 64;
-    # pallas kernel tile: 8).
+    # pallas kernel tile: 128).
     proximity_block: Optional[int] = None
     # Distance-store memory policy (repro.core.engine.memory.MemoryPolicy):
     # "auto" | "dense" | "banded" | "condensed_only" | "spilled".  All modes

@@ -3,10 +3,10 @@ from __future__ import annotations
 
 import jax
 
-from repro.kernels.proximity.proximity import proximity_pallas
+from repro.kernels.proximity.proximity import BLOCK, proximity_pallas
 
 
-def proximity(U: jax.Array, *, measure: str = "eq3", bk: int = 8) -> jax.Array:
+def proximity(U: jax.Array, *, measure: str = "eq3", bk: int = BLOCK) -> jax.Array:
     """(K, n, p) signatures -> (K, K) proximity matrix (degrees).
 
     ``measure`` is "eq3" (trace angle) or "eq2" (smallest principal angle).

@@ -18,6 +18,7 @@ import numpy as np
 
 from repro.core.angles import proximity_matrix
 from repro.core.engine import ClusterEngine, EngineConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import REPRESENTATIVE_KINDS, AssignmentServer
 
 
@@ -46,6 +47,7 @@ def main():
     ap.add_argument("--churn", type=int, default=8,
                     help="joins to submit + drain mid-serve (0 disables)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     K, Q, B = args.clients, args.queries, args.batch
     U_all = _clustered_signatures(K + Q + args.churn, n_bases=args.n_bases)

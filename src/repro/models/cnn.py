@@ -68,7 +68,6 @@ def init_lenet5(key, *, in_hw=(16, 16), in_ch=3, n_classes=10):
         "f1": _fc_init(ks[2], flat, 120),
         "f2": _fc_init(ks[3], 120, 84),
         "f3": _fc_init(ks[4], 84, n_classes),
-        "_meta": {"in_hw": jnp.array(in_hw), "in_ch": jnp.array(in_ch)},
     }
 
 

@@ -46,6 +46,49 @@ class TestProximityKernel:
         np.testing.assert_allclose(got, want, atol=1e-2)
 
 
+class TestProximityKernelLayout:
+    """The chip layout: (p, K, n) slabs, 128-edge tiles, the kernel's arccos."""
+
+    def test_arccos01_matches_jnp_arccos(self):
+        from repro.core.measures import arccos01
+
+        x = jnp.concatenate([
+            jnp.linspace(0.0, 1.0, 200_001, dtype=jnp.float32),
+            1.0 - jnp.logspace(-7, -1, 1000, dtype=jnp.float32),
+        ])
+        err = np.abs(np.asarray(arccos01(x)) - np.asarray(jnp.arccos(x)))
+        assert err.max() <= 1e-6
+
+    @pytest.mark.parametrize("measure", ["eq3", "eq2"])
+    def test_default_tile_multi_cell_grid(self, measure):
+        # K=260 pads to 384 = three 128-edge tiles per side
+        from repro.kernels.proximity.proximity import BLOCK
+
+        U = jax.vmap(lambda k: jnp.linalg.qr(jax.random.normal(k, (24, 3)))[0])(
+            jax.random.split(KEY, 260)
+        )
+        assert BLOCK == 128
+        got = np.asarray(proximity(U, measure=measure))
+        want = np.asarray(proximity_ref(U, measure=measure))
+        np.testing.assert_allclose(got, want, atol=1e-3)
+
+    @pytest.mark.parametrize("measure", ["eq3", "eq2"])
+    def test_measure_tile_slabs_match_measure_pair(self, measure):
+        from repro.core.measures import measure_pair, measure_tile
+
+        ka, kb = jax.random.split(KEY)
+        Ui = jax.vmap(lambda k: jnp.linalg.qr(jax.random.normal(k, (20, 4)))[0])(
+            jax.random.split(ka, 6)
+        )
+        Uj = jax.vmap(lambda k: jnp.linalg.qr(jax.random.normal(k, (20, 4)))[0])(
+            jax.random.split(kb, 9)
+        )
+        tile = measure_tile(Ui.transpose(2, 0, 1), Uj.transpose(2, 0, 1), measure)
+        np.testing.assert_allclose(
+            np.asarray(tile), np.asarray(measure_pair(Ui, Uj, measure)), atol=1e-4
+        )
+
+
 class TestTsgemmKernel:
     @pytest.mark.parametrize("m,k,p", [(128, 128, 8), (512, 300, 10),
                                        (1000, 768, 13), (50, 40, 3)])

@@ -196,6 +196,20 @@ class TestPaperModels:
         assert logits.shape == (4, 10)
         assert np.isfinite(np.asarray(logits)).all()
 
+    def test_lenet5_trains_at_cifar_width(self):
+        """Every param leaf must be differentiable (jax.grad refuses integer
+        leaves), and fl_train's model builder must pass the image size to
+        apply as well as init: 3072 features = 32x32x3."""
+        from repro.fl.client import ce_loss
+        from repro.launch.fl_train import build_model
+
+        init_fn, apply_fn = build_model("lenet5", 3072, 10)
+        p = init_fn(KEY)
+        x = jax.random.normal(KEY, (4, 3072))
+        y = jnp.arange(4) % 10
+        g = jax.grad(lambda q: ce_loss(apply_fn, q, x, y))(p)
+        assert all(np.isfinite(np.asarray(l)).all() for l in jax.tree.leaves(g))
+
     def test_resnet9(self):
         p = init_resnet9(KEY, in_ch=3, n_classes=100)
         x = jax.random.normal(KEY, (2, 768))

@@ -64,6 +64,16 @@ class TestBackendParity:
             assert np.isfinite(A).all(), (backend, measure)
             assert (A >= -1e-4).all()
 
+    @pytest.mark.parametrize("measure", MEASURES)
+    def test_pallas_default_tile_spans_two_cells(self, measure):
+        """block_size=None takes the kernel's 128 edge: K=130 pads to a
+        2x2 grid whose padded rows/cols must not leak into A."""
+        U = _signatures(130, n=16)
+        ref = np.asarray(proximity_matrix(U, measure, backend="jnp"))
+        got = np.asarray(proximity_matrix(U, measure, backend="pallas"))
+        assert got.shape == (130, 130)
+        np.testing.assert_allclose(got, ref, atol=1e-3)
+
     def test_auto_resolves_and_matches(self):
         U = _signatures(9)
         ref = np.asarray(proximity_matrix(U, "eq3", backend="jnp"))
