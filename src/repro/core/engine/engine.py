@@ -41,6 +41,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -55,6 +56,7 @@ from repro.core.engine.memory import MemoryPolicy
 from repro.core.engine.sanitize import allow_dense
 from repro.core.engine.store import CondensedDistances
 from repro.core.hc import CondensedWorkingMatrix, labels_from_members, merge_forest
+from repro.tracing import span
 
 
 @dataclass(frozen=True)
@@ -119,6 +121,13 @@ class EngineConfig:
             spill_dir=self.spill_dir,
             spill_segment_rows=self.spill_segment_rows,
         )
+
+
+def _count_replay(handle, stats: ReplayStats) -> None:
+    """Attach a replay's work counts to its span."""
+    handle.count("promotions", stats.promotions)
+    handle.count("dirty_merges", stats.dirty_merges)
+    handle.count("script_applied", stats.script_applied)
 
 
 @dataclass
@@ -211,16 +220,23 @@ class ClusterEngine:
     ) -> "ClusterEngine":
         """One-shot phase: proximity matrix + HC, with the script cached."""
         eng = cls(config)
-        A = np.asarray(
-            proximity_matrix(
-                U_stack,
-                measure=config.measure,
-                backend=config.backend,
-                block_size=config.block_size,
-            ),
-            dtype=np.float32,
-        )
-        eng._bootstrap(A, jnp.asarray(U_stack))
+        with span("engine.bootstrap") as sp:
+            sp.count("K", int(U_stack.shape[0]))
+            with span("bootstrap.proximity"):
+                A_dev = proximity_matrix(
+                    U_stack,
+                    measure=config.measure,
+                    backend=config.backend,
+                    block_size=config.block_size,
+                )
+            with span("bootstrap.device_wait"):
+                jax.block_until_ready(A_dev)
+            with span("bootstrap.readback") as rb:
+                A = np.asarray(A_dev, dtype=np.float32)
+                rb.count("bytes", A.nbytes)
+            with span("bootstrap.upload"):
+                U_dev = jnp.asarray(U_stack)
+            eng._bootstrap(A, U_dev)
         return eng
 
     @classmethod
@@ -233,38 +249,43 @@ class ClusterEngine:
         return eng
 
     def _bootstrap(self, A: np.ndarray, U_stack: jnp.ndarray) -> None:
-        K = int(A.shape[0])
-        if U_stack.shape[0] != K:
-            raise ValueError("A and U_stack disagree on the client count")
-        self.store = CondensedDistances.from_dense(
-            A, policy=self.config.memory_policy()
-        )
-        self.U = U_stack
-        self.ids = np.arange(K, dtype=np.int64)
-        self._next_id = K
-        self.store.memory.begin_op(self.store)
-        # Bootstrap working matrix: the dense tier runs the merge loop on a
-        # transient (K, K) float64 (fastest); the other tiers run the
-        # (K, K)-free strided path on a condensed float64 working vector —
-        # half the dense float64 footprint, bitwise-identical merges.  The
-        # vector is built from the store's segment-aware condensed source,
-        # so a spilled store streams it one cold segment at a time instead
-        # of materializing the full float32 vector first.
-        if self.store.cache_enabled:
-            work = self.store.dense(np.float64)
-        else:
-            work = CondensedWorkingMatrix(self.store.condensed_source(), K)
-        active, members, merges = merge_forest(
-            work,
-            np.ones(K, dtype=np.int64),
-            [[i] for i in range(K)],
-            **self._criterion(),
-        )
-        self._script = merges
-        self._canonical = labels_from_members(active, members, K)
-        self._stable = self._canonical.copy()
-        self.last_stats = None
-        self.version += 1
+        with span("engine.hc"):
+            K = int(A.shape[0])
+            if U_stack.shape[0] != K:
+                raise ValueError("A and U_stack disagree on the client count")
+            with span("store.condense"):
+                self.store = CondensedDistances.from_dense(
+                    A, policy=self.config.memory_policy()
+                )
+            self.U = U_stack
+            self.ids = np.arange(K, dtype=np.int64)
+            self._next_id = K
+            self.store.memory.begin_op(self.store)
+            # Bootstrap working matrix: the dense tier runs the merge loop on a
+            # transient (K, K) float64 (fastest); the other tiers run the
+            # (K, K)-free strided path on a condensed float64 working vector —
+            # half the dense float64 footprint, bitwise-identical merges.  The
+            # vector is built from the store's segment-aware condensed source,
+            # so a spilled store streams it one cold segment at a time instead
+            # of materializing the full float32 vector first.
+            with span("hc.working"):
+                if self.store.cache_enabled:
+                    work = self.store.dense(np.float64)
+                else:
+                    work = CondensedWorkingMatrix(self.store.condensed_source(), K)
+            with span("hc.merge_forest") as sp:
+                active, members, merges = merge_forest(
+                    work,
+                    np.ones(K, dtype=np.int64),
+                    [[i] for i in range(K)],
+                    **self._criterion(),
+                )
+                sp.count("merges", len(merges))
+            self._script = merges
+            self._canonical = labels_from_members(active, members, K)
+            self._stable = self._canonical.copy()
+            self.last_stats = None
+            self.version += 1
 
     # -- views --------------------------------------------------------------
 
@@ -366,63 +387,72 @@ class ClusterEngine:
         B = int(U_new.shape[0])
         if B == 0:
             raise ValueError("admit needs at least one newcomer")
-        M = self.store.n
-        cfg = self.config
-        if M == 0:
-            nid0, ver0 = self._next_id, self.version
-            eng = ClusterEngine.from_signatures(U_new, cfg)
-            self.__dict__.update(eng.__dict__)
-            # stable ids / version continue from the pre-churn lineage
-            self.ids = np.arange(nid0, nid0 + B, dtype=np.int64)
-            self._next_id = nid0 + B
-            self.version = ver0 + 1
-            stats = ReplayStats()
+        with span("engine.admit") as sp:
+            sp.count("B", B)
+            M = self.store.n
+            cfg = self.config
+            if M == 0:
+                nid0, ver0 = self._next_id, self.version
+                eng = ClusterEngine.from_signatures(U_new, cfg)
+                self.__dict__.update(eng.__dict__)
+                # stable ids / version continue from the pre-churn lineage
+                self.ids = np.arange(nid0, nid0 + B, dtype=np.int64)
+                self._next_id = nid0 + B
+                self.version = ver0 + 1
+                stats = ReplayStats()
+                self.last_stats = stats
+                return AdmitResult(
+                    ids=self.ids.copy(),
+                    labels=self._stable.copy(),
+                    newcomer_labels=self._stable.copy(),
+                    new_cluster=np.ones(B, dtype=bool),
+                    canonical=self._canonical.copy(),
+                    stats=stats,
+                )
+            from repro.core.pme import proximity_blocks
+
+            with span("admit.cross_block") as cb:
+                cross, square = proximity_blocks(
+                    self.U, U_new,
+                    measure=cfg.measure, backend=cfg.backend, block_size=cfg.block_size,
+                )
+                cb.count("pairs", M * B)
+            with span("store.append"):
+                self.store.append_block(cross, square)
+            with span("engine.stack"):
+                self.U = jnp.concatenate([self.U, U_new.astype(self.U.dtype)], axis=0)
+            new_ids = np.arange(self._next_id, self._next_id + B, dtype=np.int64)
+            self._next_id += B
+            self.ids = np.concatenate([self.ids, new_ids])
+
+            with span("engine.replay") as rp:
+                canonical, script, stats = replay(
+                    self.store,
+                    self._script,
+                    [[M + t] for t in range(B)],
+                    **self._criterion(),
+                )
+                _count_replay(rp, stats)
+            old_stable = self._stable
+            with span("engine.remap"):
+                stable = remap_onto_old_ids(canonical, old_stable, M)
+            self._canonical = canonical
+            self._stable = stable
+            self._script = script
             self.last_stats = stats
+            self.version += 1
+            seen = set(stable[:M].tolist())
+            newcomer_labels = stable[M:]
             return AdmitResult(
-                ids=self.ids.copy(),
-                labels=self._stable.copy(),
-                newcomer_labels=self._stable.copy(),
-                new_cluster=np.ones(B, dtype=bool),
-                canonical=self._canonical.copy(),
+                ids=new_ids,
+                labels=stable.copy(),
+                newcomer_labels=newcomer_labels.copy(),
+                new_cluster=np.array(
+                    [l not in seen for l in newcomer_labels], dtype=bool
+                ),
+                canonical=canonical.copy(),
                 stats=stats,
             )
-        from repro.core.pme import proximity_blocks
-
-        cross, square = proximity_blocks(
-            self.U, U_new,
-            measure=cfg.measure, backend=cfg.backend, block_size=cfg.block_size,
-        )
-        self.store.append_block(cross, square)
-        self.U = jnp.concatenate([self.U, U_new.astype(self.U.dtype)], axis=0)
-        new_ids = np.arange(self._next_id, self._next_id + B, dtype=np.int64)
-        self._next_id += B
-        self.ids = np.concatenate([self.ids, new_ids])
-
-        canonical, script, stats = replay(
-            self.store,
-            self._script,
-            [[M + t] for t in range(B)],
-            **self._criterion(),
-        )
-        old_stable = self._stable
-        stable = remap_onto_old_ids(canonical, old_stable, M)
-        self._canonical = canonical
-        self._stable = stable
-        self._script = script
-        self.last_stats = stats
-        self.version += 1
-        seen = set(stable[:M].tolist())
-        newcomer_labels = stable[M:]
-        return AdmitResult(
-            ids=new_ids,
-            labels=stable.copy(),
-            newcomer_labels=newcomer_labels.copy(),
-            new_cluster=np.array(
-                [l not in seen for l in newcomer_labels], dtype=bool
-            ),
-            canonical=canonical.copy(),
-            stats=stats,
-        )
 
     def depart(self, client_ids: np.ndarray) -> DepartResult:
         """Remove clients (churn) — the symmetric delete to :meth:`admit`.
@@ -444,51 +474,60 @@ class ClusterEngine:
         if pos.size != np.unique(client_ids).size:
             missing = np.setdiff1d(client_ids, self.ids)
             raise KeyError(f"unknown client ids: {missing.tolist()}")
-        K = self.store.n
-        departed_ids = self.ids[pos].copy()
-        if pos.size == K:  # everyone leaves
-            cfg = self.config
-            nid, ver = self._next_id, self.version
-            self.__init__(cfg)
-            # stable ids / version continue from the pre-churn lineage,
-            # mirroring the admit-into-empty path
-            self._next_id = nid
-            self.version = ver + 1
-            stats = ReplayStats()
+        with span("engine.depart") as sp:
+            sp.count("B", int(pos.size))
+            K = self.store.n
+            departed_ids = self.ids[pos].copy()
+            if pos.size == K:  # everyone leaves
+                cfg = self.config
+                nid, ver = self._next_id, self.version
+                self.__init__(cfg)
+                # stable ids / version continue from the pre-churn lineage,
+                # mirroring the admit-into-empty path
+                self._next_id = nid
+                self.version = ver + 1
+                stats = ReplayStats()
+                self.last_stats = stats
+                return DepartResult(
+                    departed=departed_ids,
+                    labels=self._stable.copy(),
+                    canonical=self._canonical.copy(),
+                    stats=stats,
+                )
+            with span("store.remove") as rm:
+                keep = self.store.remove(pos)
+                rm.count("removed", int(pos.size))
+            with span("depart.script"):
+                kept_script = filter_script_for_depart(self._script, K, pos)
+                inv = np.full(K, -1, dtype=np.int64)
+                inv[keep] = np.arange(keep.size, dtype=np.int64)
+                script_new = [
+                    (int(inv[a]), int(inv[b]) if b >= 0 else -1, h)
+                    for a, b, h in kept_script
+                ]
+            with span("engine.stack"):
+                self.U = jnp.take(self.U, jnp.asarray(keep), axis=0)
+            old_stable = self._stable[keep]
+            self.ids = self.ids[keep]
+
+            with span("engine.replay") as rp:
+                canonical, script, stats = replay(
+                    self.store, script_new, [], **self._criterion()
+                )
+                _count_replay(rp, stats)
+            with span("engine.remap"):
+                stable = remap_onto_old_ids(canonical, old_stable, self.store.n)
+            self._canonical = canonical
+            self._stable = stable
+            self._script = script
             self.last_stats = stats
+            self.version += 1
             return DepartResult(
                 departed=departed_ids,
-                labels=self._stable.copy(),
-                canonical=self._canonical.copy(),
+                labels=stable.copy(),
+                canonical=canonical.copy(),
                 stats=stats,
             )
-        kept_script = filter_script_for_depart(self._script, K, pos)
-        keep = self.store.remove(pos)
-        inv = np.full(K, -1, dtype=np.int64)
-        inv[keep] = np.arange(keep.size, dtype=np.int64)
-        script_new = [
-            (int(inv[a]), int(inv[b]) if b >= 0 else -1, h)
-            for a, b, h in kept_script
-        ]
-        self.U = jnp.take(self.U, jnp.asarray(keep), axis=0)
-        old_stable = self._stable[keep]
-        self.ids = self.ids[keep]
-
-        canonical, script, stats = replay(
-            self.store, script_new, [], **self._criterion()
-        )
-        stable = remap_onto_old_ids(canonical, old_stable, self.store.n)
-        self._canonical = canonical
-        self._stable = stable
-        self._script = script
-        self.last_stats = stats
-        self.version += 1
-        return DepartResult(
-            departed=departed_ids,
-            labels=stable.copy(),
-            canonical=canonical.copy(),
-            stats=stats,
-        )
 
     def move(self, client_ids: np.ndarray, U_new: jnp.ndarray) -> MoveResult:
         """Fused depart+admit: migrate drifted clients in ONE replay pass.

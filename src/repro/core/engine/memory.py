@@ -53,6 +53,8 @@ from typing import Optional
 
 import numpy as np
 
+from repro.tracing import span
+
 MEMORY_MODES = ("auto", "dense", "banded", "condensed_only", "spilled")
 
 # auto-mode byte budget for cache structures (the persistent condensed
@@ -366,6 +368,8 @@ class StoreMemory:
                 # the K/8 threshold stay on strided condensed reads.
                 if not store.has_dense_cache:
                     self.stats.densifications += 1
+                    with span("store.densify"):
+                        store.dense_ro()
                 out = store.dense_ro()[idx].astype(np.float64)
             else:
                 out = store.rows(idx)

@@ -43,6 +43,7 @@ import numpy as np
 from repro.fl.churn import ChurnQueue
 from repro.serving.dispatch import serve_assign
 from repro.serving.representatives import RepresentativeCache
+from repro.tracing import span
 
 
 @dataclass(frozen=True)
@@ -241,24 +242,28 @@ class AssignmentServer:
         """Queue a join (signature computed eagerly by the queue's
         ``signature_fn``); returns the stable id the client will hold once
         a drain admits it."""
-        self.queue.enqueue_join(payload)
-        cid = self._projected_next
-        self._projected.append(cid)
-        self._projected_next += 1
+        with span("serve.submit") as sp:
+            sp.count("join", 1)
+            self.queue.enqueue_join(payload)
+            cid = self._projected_next
+            self._projected.append(cid)
+            self._projected_next += 1
         return cid
 
     def submit_leave(self, client_id: int) -> None:
         """Queue a departure by **stable client id** (including an id a
         prior ``submit_join`` predicted).  KeyError if unknown."""
-        cid = int(client_id)
-        try:
-            pos = self._projected.index(cid)
-        except ValueError:
-            raise KeyError(
-                f"client id {cid} not in projected membership"
-            ) from None
-        self.queue.enqueue_leave(pos)
-        self._projected.pop(pos)
+        with span("serve.submit") as sp:
+            sp.count("leave", 1)
+            cid = int(client_id)
+            try:
+                pos = self._projected.index(cid)
+            except ValueError:
+                raise KeyError(
+                    f"client id {cid} not in projected membership"
+                ) from None
+            self.queue.enqueue_leave(pos)
+            self._projected.pop(pos)
 
     def drain(self, *, force: bool = True) -> DrainReport:
         """Apply queued churn to the live engine and epoch-swap.
@@ -274,21 +279,26 @@ class AssignmentServer:
         labels are a pure function of the distance store, any drain
         slicing reproduces the synchronous schedule's labels bitwise.
         """
-        batches = self.queue.drain(force=force)
-        joins = leaves = 0
-        for batch in batches:
-            if batch.leave:
-                gone, _ = batch.resolve_leaves(self._write.ids)
-                self._write.depart(np.asarray(gone, dtype=np.int64))
-                leaves += len(gone)
-            if batch.join:
-                sigs = batch.signatures
-                if sigs is None:
-                    sigs = jnp.stack([jnp.asarray(j) for j in batch.join])
-                self._write.admit(sigs)
-                joins += len(batch.join)
-        if batches:
-            self._commit()
+        with span("serve.drain") as sp:
+            with span("queue.drain"):
+                batches = self.queue.drain(force=force)
+            joins = leaves = 0
+            for batch in batches:
+                if batch.leave:
+                    gone, _ = batch.resolve_leaves(self._write.ids)
+                    self._write.depart(np.asarray(gone, dtype=np.int64))
+                    leaves += len(gone)
+                if batch.join:
+                    sigs = batch.signatures
+                    if sigs is None:
+                        sigs = jnp.stack([jnp.asarray(j) for j in batch.join])
+                    self._write.admit(sigs)
+                    joins += len(batch.join)
+            if batches:
+                self._commit()
+            sp.count("batches", len(batches))
+            sp.count("joins", joins)
+            sp.count("leaves", leaves)
         return DrainReport(
             epoch=self._epoch,
             batches=len(batches),
@@ -304,17 +314,23 @@ class AssignmentServer:
         return self._snapshot
 
     def _commit(self) -> None:
-        fork = self._write.copy()
-        self.reps.refresh(fork)
-        self._epoch += 1
-        cfg = fork.config
-        self._snapshot = ServingSnapshot(
-            epoch=self._epoch,
-            engine=fork,
-            rep_stack=self.reps.rep_stack,
-            rep_labels=self.reps.rep_labels.copy(),
-            beta=None if cfg.n_clusters is not None else float(cfg.beta),
-        )
+        with span("serve.commit"):
+            with span("commit.fork"):
+                fork = self._write.copy()
+            with span("commit.refresh") as sp:
+                rebuilt, reused = self.reps.rebuilt, self.reps.reused
+                self.reps.refresh(fork)
+                sp.count("rebuilt", self.reps.rebuilt - rebuilt)
+                sp.count("reused", self.reps.reused - reused)
+            self._epoch += 1
+            cfg = fork.config
+            self._snapshot = ServingSnapshot(
+                epoch=self._epoch,
+                engine=fork,
+                rep_stack=self.reps.rep_stack,
+                rep_labels=self.reps.rep_labels.copy(),
+                beta=None if cfg.n_clusters is not None else float(cfg.beta),
+            )
 
 
 def admit_oracle(engine, U_query) -> tuple[int, bool]:
