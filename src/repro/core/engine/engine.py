@@ -278,6 +278,7 @@ class ClusterEngine:
                     work,
                     np.ones(K, dtype=np.int64),
                     [[i] for i in range(K)],
+                    counts=sp,
                     **self._criterion(),
                 )
                 sp.count("merges", len(merges))

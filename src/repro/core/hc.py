@@ -15,7 +15,12 @@ The merge loop is O(K^2): a per-cluster nearest-neighbor cache (``nn`` /
 O(K^2) copy per merge, O(K^3) total — it dominated the one-shot phase once
 the proximity matrix itself got fast).  Each merge costs one vectorized
 Lance-Williams row update plus argmin rescans only for clusters whose
-cached neighbor was touched by the merge.
+cached neighbor was touched by the merge.  On a dense input every one of
+those passes runs over the live clusters only: dead rows and columns are
+masked instead of cleared, and the working matrix is compacted to the live
+set as it thins, so a merge pays for the clusters left, not for K.  Merges
+the loop would make in a row without affecting one another are applied as
+one batch, so most numpy calls are paid per batch, not per merge.
 """
 from __future__ import annotations
 
@@ -33,6 +38,15 @@ _LINKAGES = ("single", "complete", "average")
 # arithmetic bitwise-equal no matter where the rows come from (dense
 # matrix, dense cache, band, strided condensed gathers).
 ROW_BLOCK = 256
+
+# A dense merge loop copies its working matrix down to the live clusters
+# once they fall to this share of its width (so the copies sum to under one
+# full matrix), and never to fewer than COMPACT_MIN_WIDTH rows: below that
+# a merge costs its numpy calls, not its width.
+COMPACT_FRACTION = 0.7
+COMPACT_MIN_WIDTH = 256
+_COMPACT_ROWS = 16  # rows per block of the in-place compaction copy
+_BATCH_MAX = 32  # most merges one step of the dense loop applies
 
 
 def condensed_row_gather(
@@ -242,41 +256,6 @@ class CondensedWorkingMatrix:
         return nn, nnd
 
 
-class _DenseWorking:
-    """Adapter giving a dense (K, K) float64 matrix the same row interface
-    (views, not copies — the ops below are bitwise the pre-refactor code)."""
-
-    __slots__ = ("D",)
-
-    def __init__(self, D: np.ndarray):
-        self.D = D
-
-    @property
-    def shape(self):
-        return self.D.shape
-
-    def row(self, i):
-        return self.D[i]
-
-    def write_row(self, i, vals):
-        self.D[i, :] = vals
-        self.D[:, i] = vals
-
-    def clear_row(self, j):
-        self.D[j, :] = np.inf
-        self.D[:, j] = np.inf
-
-    def argmin_row(self, k):
-        r = self.D[k]
-        a = int(r.argmin())
-        return a, r[a]
-
-    def prepare(self):
-        np.fill_diagonal(self.D, np.inf)
-        nn = self.D.argmin(axis=1)
-        return nn, self.D[np.arange(self.D.shape[0]), nn]
-
-
 def lance_williams(
     di: np.ndarray, dj: np.ndarray, si, sj, linkage: str
 ) -> np.ndarray:
@@ -300,6 +279,7 @@ def merge_forest(
     beta: Optional[float] = None,
     n_clusters: Optional[int] = None,
     linkage: str = "average",
+    counts=None,
 ) -> tuple[np.ndarray, list[list[int]], list[tuple[int, int, float]]]:
     """Core agglomerative merge loop, generalized to non-singleton starts.
 
@@ -307,15 +287,22 @@ def merge_forest(
     clusters: ``D`` is the (C, C) float64 cluster-distance matrix — either a
     dense ndarray or a :class:`CondensedWorkingMatrix` (the strided path the
     streaming engine's ``banded`` / ``condensed_only`` memory tiers use for
-    a (K, K)-free bootstrap; both are CONSUMED — mutated in place, diagonal
-    read as inf).  ``size[i]`` is the member count and ``members[i]`` the
-    client ids of initial cluster ``i``.  For tie-breaking to match a
-    singleton-start run on the same leaves, initial clusters must be ordered
-    by their smallest member id (rows then stand in for leaf indices:
-    merging keeps the smaller row, so a row's id stays the min member of its
-    cluster).  The two input paths produce bitwise-identical merges: the
-    condensed path gathers rows holding exactly the values the dense rows
-    would, and the loop's arithmetic is shared.
+    a (K, K)-free bootstrap).  ``size[i]`` is the member count and
+    ``members[i]`` the client ids of initial cluster ``i``.  ``D`` and
+    ``size`` are CONSUMED: mutated in place (diagonal read as inf), and a
+    dense ``D`` is compacted to its live clusters as they thin (into the
+    front of its own buffer), after which ``size`` is carried as a smaller
+    copy.  For tie-breaking to match a singleton-start run on the same
+    leaves, initial clusters must be ordered by their smallest member id
+    (rows then stand in for leaf indices: merging keeps the smaller row, so
+    a row's id stays the min member of its cluster; compaction keeps the
+    live rows in ascending order, so this holds across it).  The two input
+    paths produce bitwise-identical merges: the condensed path gathers rows
+    holding exactly the values the dense rows would, and both loops do the
+    same float64 arithmetic in the same order.  ``counts`` (a
+    :mod:`repro.tracing` span handle, or anything with ``count(key, n)``)
+    receives ``compactions`` and ``width_sum``, the working width summed
+    over the merges; the condensed path never compacts.
 
     Returns ``(active, members, merges)``: the liveness mask, the merged
     member lists, and the merge script — ``(rep_i, rep_j, height)`` per merge
@@ -328,7 +315,9 @@ def merge_forest(
         raise ValueError("specify exactly one of beta / n_clusters")
     if linkage not in _LINKAGES:
         raise ValueError(f"linkage must be one of {_LINKAGES}")
-    work = D if isinstance(D, CondensedWorkingMatrix) else _DenseWorking(D)
+    if not isinstance(D, CondensedWorkingMatrix):
+        return _merge_dense(D, size, members, beta, n_clusters, linkage, counts)
+    work = D
     K = work.shape[0]
     merges: list[tuple[int, int, float]] = []
     active = np.ones(K, dtype=bool)
@@ -384,7 +373,203 @@ def merge_forest(
         nn_dist[better] = new[better]
         nn[i], nn_dist[i] = work.argmin_row(i)
 
+    if counts is not None:
+        counts.count("compactions", 0)
+        counts.count("width_sum", K * len(merges))
     return active, members, merges
+
+
+def _merge_dense(D, size, members, beta, n_clusters, linkage, counts):
+    """:func:`merge_forest` on a dense (C, C) float64 matrix, over the live
+    clusters only, several merges per step.
+
+    Same picks, ties, order and float64 arithmetic as the condensed loop;
+    what differs is how the work is laid out.
+
+    * A step applies a batch of the merges the one-at-a-time loop would make
+      next (:func:`_batch_picks`, :func:`_batch_kept`).  Their Lance-Williams
+      rows are computed together; where a later merge's rows meet an earlier
+      merge's column, that entry is recomputed from the earlier result, as
+      the sequence would have it.  Rows whose neighbor merged or died are
+      rescanned once per step, and every other live row compares its cache
+      with the closest merged column (lowest index on ties), which is what
+      the per-merge updates compose to.
+    * A merged-away cluster's row and column are left stale instead of
+      cleared to inf: rescans mask the dead columns (``dead[:n_dead]``) and
+      the neighbor update masks the dead rows.
+    * Once the live count falls to ``COMPACT_FRACTION`` of the working
+      width, the live rows and columns are copied, in ascending order, to
+      the front of the same buffer (``orig`` maps working rows back to input
+      rows).  Ascending order keeps ``np.argmin``'s first-occurrence ties and
+      "the smaller row survives" exactly as they were on the full matrix.
+    """
+    K = D.shape[0]
+    merges: list[tuple[int, int, float]] = []
+    active = np.ones(K, dtype=bool)  # by input row, what the caller gets back
+    compactions = width_sum = 0
+    if K > 1:
+        D = np.ascontiguousarray(D)  # compaction reuses the buffer in place
+        np.fill_diagonal(D, np.inf)
+        nn = D.argmin(axis=1)
+        nn_dist = D[np.arange(K), nn]
+        live = np.ones(K, dtype=bool)  # by working row
+        dead = np.empty(K, dtype=np.int64)
+        n_dead = 0
+        orig = np.arange(K)
+        rep = [min(m) for m in members]  # by input row
+        remaining = K
+        target = 1 if n_clusters is None else max(int(n_clusters), 1)
+        while remaining > target:
+            w = D.shape[0]
+            first = int(nn_dist.argmin())
+            if beta is not None and nn_dist[first] > beta:
+                break
+            C = _batch_picks(nn, nn_dist, first, min(_BATCH_MAX, remaining - target), beta)
+            A = np.minimum(C, nn[C])
+            B = np.maximum(C, nn[C])
+            sa, sb = size[A][:, None], size[B][:, None]
+            new = lance_williams(D[A], D[B], sa, sb, linkage)
+            if C.size > 1:
+                # merge t reads merge s's column (s < t): its two rows' entries
+                # there are merge s's results
+                t, s = np.tril_indices(C.size, -1)
+                new[t, A[s]] = lance_williams(
+                    new[s, A[t]], new[s, B[t]], sa[t, 0], sb[t, 0], linkage)
+                k = _batch_kept(new, nn_dist[C], A, B, s, t, dead[:n_dead])
+                if k < C.size:
+                    C, A, B, new = C[:k], A[:k], B[:k], new[:k]
+                    s, t = s[t < k], t[t < k]
+                new[s, A[t]] = new[t, A[s]]  # a pair ends at the later merge's value
+            k = C.size
+            new[np.arange(k), A] = np.inf
+            D[A] = new
+            D[:, A] = new.T
+            for a, b, h in zip(A.tolist(), B.tolist(), nn_dist[C].tolist()):
+                oa, ob = int(orig[a]), int(orig[b])
+                merges.append((rep[oa], rep[ob], h))
+                rep[oa] = min(rep[oa], rep[ob])
+                members[oa].extend(members[ob])
+                active[ob] = False
+            size[A] += size[B]
+            live[B] = False
+            dead[n_dead : n_dead + k] = B
+            n_dead += k
+            nn[B] = -1
+            nn_dist[B] = np.inf
+            remaining -= k
+            width_sum += k * w
+
+            # Rows whose cached neighbor merged or died rescan, with the merged
+            # rows (one 2-D argmin, dead columns masked, first occurrence per
+            # row); every other live row can only have been improved by a
+            # merged column.  The tie rule (equal distance, lower index wins)
+            # mirrors argmin.
+            hit = np.zeros(w + 1, dtype=bool)  # a dead row's nn -1 reads hit[w]
+            hit[A] = True
+            hit[B] = True
+            touched = hit[nn]
+            touched[A] = False
+            by_col = np.argsort(A)
+            cols = new[by_col]
+            r = cols.argmin(axis=0)
+            near = cols[r, np.arange(w)]
+            near_col = A[by_col][r]
+            better = (near < nn_dist) | ((near == nn_dist) & (near_col < nn))
+            better &= live
+            better &= ~touched
+            better[A] = False
+            nn[better] = near_col[better]
+            np.copyto(nn_dist, near, where=better)
+            rows = np.concatenate([A, np.flatnonzero(touched)])
+            R = D[rows]
+            R[:, dead[:n_dead]] = np.inf
+            a = R.argmin(axis=1)
+            nn[rows] = a
+            nn_dist[rows] = R[np.arange(rows.size), a]
+
+            if (remaining <= COMPACT_FRACTION * w
+                    and remaining >= COMPACT_MIN_WIDTH and remaining > target):
+                keep = np.flatnonzero(live)
+                D = _compact(D, keep)
+                renum = np.zeros(w, dtype=nn.dtype)
+                renum[keep] = np.arange(keep.size)
+                nn = renum[nn[keep]]
+                nn_dist, size, orig = nn_dist[keep], size[keep], orig[keep]
+                live = np.ones(keep.size, dtype=bool)
+                n_dead = 0
+                compactions += 1
+
+    if counts is not None:
+        counts.count("compactions", compactions)
+        counts.count("width_sum", width_sum)
+    return active, members, merges
+
+
+def _batch_picks(nn, nn_dist, first, room, beta):
+    """Rows whose merges can be batched behind ``first``, the closest row.
+
+    Walks the rows in (distance, index) order, the order in which the
+    one-at-a-time loop meets them, skipping rows already merged in the
+    batch, and stops at the first row whose cached neighbor the batch has
+    merged (its distance would change), at a distance not below every
+    listed row's (an unlisted row could tie), past ``beta``, or at ``room``
+    picks.  :func:`_batch_kept` then checks the picks against the merged
+    rows themselves.
+    """
+    picks = [first]
+    if room > 1:
+        m = min(2 * _BATCH_MAX, nn_dist.size)
+        cand = np.argpartition(nn_dist, m - 1)[:m]
+        dist = nn_dist[cand]
+        order = np.lexsort((cand, dist))
+        limit = dist.max()
+        used = {first, int(nn[first])}
+        for c, d in zip(cand[order].tolist(), dist[order].tolist()):
+            if c in used:
+                continue
+            if not d < limit or (beta is not None and d > beta):
+                break
+            p = int(nn[c])
+            if p in used:
+                break
+            picks.append(c)
+            used.update((c, p))
+            if len(picks) == room:
+                break
+    return np.array(picks)
+
+
+def _batch_kept(new, heights, A, B, s, t, dead):
+    """How many leading picks of a batch the one-at-a-time loop would make.
+
+    Pick ``t`` is its row's own cached pair, untouched by the earlier picks;
+    it is still the loop's next merge if its height is strictly below every
+    earlier merged cluster's distance to what is live at that merge
+    (``new`` row by row, dead columns masked): every other row is then at
+    least as far as before the batch, or as far as a merged cluster.
+    """
+    k = A.size
+    M = new.copy()
+    M[:, dead] = np.inf
+    M[t, B[s]] = np.inf  # merge s's dropped cluster is gone by merge t
+    M[np.arange(k), A] = np.inf
+    M[np.arange(k), B] = np.inf
+    reach = np.minimum.accumulate(M.min(axis=1))
+    ok = heights[1:] < reach[:-1]
+    return k if ok.all() else 1 + int(ok.argmin())
+
+
+def _compact(D: np.ndarray, keep: np.ndarray) -> np.ndarray:
+    """``D[np.ix_(keep, keep)]`` for ascending ``keep``, written over the
+    front of ``D``'s own buffer (C-contiguous): output row ``o`` lands at or
+    before input row ``keep[o]``, so no row is overwritten before it is read.
+    """
+    n = keep.size
+    flat = D.reshape(-1)
+    for lo in range(0, n, _COMPACT_ROWS):
+        block = D[keep[lo : lo + _COMPACT_ROWS]].take(keep, axis=1)
+        flat[lo * n : lo * n + block.size] = block.ravel()
+    return flat[: n * n].reshape(n, n)
 
 
 def labels_from_members(

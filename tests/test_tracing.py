@@ -20,6 +20,7 @@ import pytest
 
 from conftest import clustered_signatures
 from repro import tracing
+from repro.core import hc
 from repro.core.angles import proximity_matrix
 from repro.core.engine import ClusterEngine, EngineConfig
 from repro.serving import AssignmentServer
@@ -125,7 +126,10 @@ def test_bootstrap_spans(traced):
         assert r.root == top.id, name
         assert top.start_ns <= r.start_ns <= r.end_ns <= top.end_ns
     eng = traced["engine"]
-    assert by["hc.merge_forest"][0].counts == {"merges": len(eng._script)}
+    # K is below the compaction width: every merge ran at full width
+    merges = len(eng._script)
+    assert by["hc.merge_forest"][0].counts == {
+        "merges": merges, "compactions": 0, "width_sum": merges * K}
     assert by["bootstrap.readback"][0].counts == {"bytes": 4 * K * K}
 
 
@@ -198,6 +202,32 @@ def test_densify_span_where_replay_crosses_the_threshold(tmp_path):
     by = _by_name(recs)
     assert len(by["store.densify"]) == 1
     assert by["store.densify"][0].parent == by["engine.replay"][0].id
+
+
+@pytest.mark.parametrize("n", [hc.COMPACT_MIN_WIDTH - 1, 3 * hc.COMPACT_MIN_WIDTH])
+def test_merge_forest_span_counts_compactions_and_width(tmp_path, n):
+    """A dense-tier bootstrap above the compaction width compacts its working
+    matrix and pays for fewer columns than ``merges * K``; one below it never
+    compacts."""
+    rng = np.random.default_rng(n)
+    X = rng.normal(size=(n, 4)) + 8.0 * rng.integers(0, 6, (n, 1))
+    A = np.sqrt(((X[:, None] - X[None]) ** 2).sum(-1)).astype(np.float32)
+    U = np.zeros((n, 2, 1), dtype=np.float32)
+    cfg = EngineConfig(n_clusters=6, memory="dense")
+    tracing.reset()
+    with jax.profiler.trace(str(tmp_path)):
+        eng = ClusterEngine.from_proximity(A, U, cfg)
+    recs = tracing.records()
+    tracing.reset()
+    (rec,) = _by_name(recs)["hc.merge_forest"]
+    counts = rec.counts
+    assert counts["merges"] == len(eng._script) == n - 6
+    if n < hc.COMPACT_MIN_WIDTH:
+        assert counts["compactions"] == 0
+        assert counts["width_sum"] == counts["merges"] * n
+    else:
+        assert counts["compactions"] > 0
+        assert counts["width_sum"] < counts["merges"] * n
 
 
 def test_ring_drops_oldest_and_counts_them(tmp_path):
