@@ -19,7 +19,8 @@ A driver module exposes ``make(run) -> cell``; the cell has
 A metric reader module exposes ``read(run) -> float | None`` (``None``:
 nothing to read in this run, and the metric is left out of the line) and may
 declare ``SPANS = {name: "package.module:attr"}``, the program calls it
-needs wrapped in host spans in a traced run.
+needs wrapped in host spans in a traced run.  Readers that need the same
+span declare the same name and target; it is wrapped once.
 """
 from __future__ import annotations
 
@@ -173,9 +174,13 @@ def run_cell(
     e2e_names = {m["name"] for m in e2e}
     layer = [m for m in spec["per_layer"] if workload in m["workloads"]]
     readers = {m["name"]: load_module("metrics", m["name"]) for m in layer} if trace else {}
+    wrapped: dict[str, str] = {}
     for reader in readers.values():
         for span_name, target in getattr(reader, "SPANS", {}).items():
-            run.spans.install(span_name, target)
+            if wrapped.setdefault(span_name, target) != target:
+                raise ValueError(f"span {span_name!r} names both {wrapped[span_name]} and {target}")
+    for span_name, target in wrapped.items():
+        run.spans.install(span_name, target)
 
     cell = load_module("drivers", traffic["driver"]).make(run)
     cell.setup()

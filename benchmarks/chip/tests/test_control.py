@@ -1,8 +1,11 @@
 """The control reads as not correct; the program, on the same seeds, as correct.
 
 The control is the plain reference put in the program's place one precision
-step lower (``reference.py``: Gram products at ``Precision.HIGH``).  That
-precision exists only on a TPU, so this test runs only where JAX finds one:
+step lower (``reference.py``: Gram products at ``Precision.HIGH``;
+``round_reference.py``: parameters and momentum in bfloat16).  That
+precision exists only on a TPU (the round's, whose program runs at the TPU's
+default precision, is judged there too), so this test runs only where JAX
+finds one:
 
     python3 -m pytest benchmarks/chip/tests/test_control.py
 
@@ -15,6 +18,7 @@ import readings
 CONFIG = {
     "boot.femnist-eq3": {"n_clients": 1024},
     "churn.femnist-eq3": {"n_clients": 1024},
+    "round.mix4-lenet5": {},
 }
 
 

@@ -43,3 +43,15 @@ def test_femnist_eq3_is_compute_bound_on_v5e():
     t, bound = flops.roofline_s(f, flops.proximity_bytes(3550, 784, 5), peak)
     assert bound == "compute" and t == pytest.approx(f / 197e12)
 
+
+
+def test_lenet5_hand_count():
+    # 32x32x3 in, 40 classes: conv1 28*28*6 outputs of 5*5*3 MACs; pool to
+    # 14x14; conv2 10*10*16 outputs of 5*5*6; pool to 5x5x16 = 400 features;
+    # fc 400*120, 120*84, 84*40
+    macs = 28 * 28 * 6 * 75 + 10 * 10 * 16 * 150 + 400 * 120 + 120 * 84 + 84 * 40
+    assert macs == 654_240
+    assert flops.lenet5_forward_macs(32, 3, 40) == macs
+    assert flops.lenet5_train_flops(32, 3, 40) == 3 * 2 * macs
+    # a MIX-4 round: 10 clients x 45 steps x 20 samples
+    assert 9000 * flops.lenet5_train_flops(32, 3, 40) == pytest.approx(35.33e9, rel=1e-3)

@@ -72,10 +72,31 @@ def test_hand_built_trace():
         "jit_f/proximity": 20e-9, "jit_f/fusion": 15e-9,
         "jit_f/copy": 10e-9, "?/proximity": 10e-9,
     })
-    # gaps [0, 10) in bootstrap, [40, 50) in hc, [60, 90) midpoint 75: no span
+    assert ts.module_s == pytest.approx({"jit_f": 50e-9})
+    assert ts.module_calls == {"jit_f": 1}
+    # gaps [0, 10) in bootstrap, [40, 50) in hc; [60, 90) split: [60, 65) in
+    # hc, [65, 70) in bootstrap, [70, 90) in no span
     assert dict(ts.idle_by_host) == pytest.approx({
-        "bootstrap": 10e-9, "hc": 10e-9, "no span": 30e-9,
+        "bootstrap": 15e-9, "hc": 15e-9, "no span": 20e-9,
     })
+
+
+def test_gap_over_sibling_spans_is_split():
+    """One idle gap that two sibling spans cover in turn goes to each by its
+    share, not whole to the span open at its midpoint."""
+    ts = trace_reduce.reduce_profile(_profile(
+        device_ops=[("%copy = x", 0, 20), ("%copy = x", 45, 55)],   # busy [0, 20), [45, 100)
+        host_spans=[
+            ("span.window", 0, 100),
+            ("span.round", 0, 30),
+            ("span.merge_forest", 10, 5),                             # inside round, before the gap
+            ("span.round", 30, 60),                                   # [30, 90)
+            ("span.run_local", 30, 10),                               # inside the second round
+        ],
+    ))
+    # the gap [20, 45): [20, 30) first round, [30, 40) run_local, [40, 45) second round
+    assert dict(ts.idle_by_host) == pytest.approx({"round": 15e-9, "run_local": 10e-9})
+    assert sum(v for _, v in ts.idle_by_host) == pytest.approx(ts.window_s - ts.busy_s)
 
 
 def test_names():

@@ -6,10 +6,13 @@
 * per-kernel device time: the summed durations of the operations whose HLO
   instruction name is the kernel's name (``%proximity.1 = ...`` is the
   kernel ``proximity``);
+* per-module device time: the summed durations of the "XLA Modules" events
+  of each jitted program (``jit_local_sgd``), clipped to the window;
 * the device operations that took most time, keyed ``<module>/<op>``;
 * idle time by what the host was doing: each gap between busy intervals is
-  attributed to the innermost host span (``span.<name>`` annotations) that
-  covers its midpoint, and the gaps are summed by span name.
+  split among the host spans (``span.<name>`` annotations) by how much of it
+  each one covers as the innermost open span, and summed by span name; what
+  no span covers is "no span".
 
 The window is the host annotation ``span.window`` that the harness opens
 around the measured loop.
@@ -68,6 +71,8 @@ class TraceSummary:
     n_devices: int
     kernel_s: dict[str, float]          # kernel -> summed device seconds
     kernel_calls: dict[str, int]
+    module_s: dict[str, float]          # jitted program -> summed device seconds
+    module_calls: dict[str, int]
     top_ops: list[tuple[str, float]]    # (module/op, seconds), longest first
     idle_by_host: list[tuple[str, float]]
 
@@ -81,32 +86,56 @@ def _events(line):
         yield ev.name, int(ev.start_ns), int(ev.start_ns + ev.duration_ns)
 
 
-def _attributed_gaps(busy, host_spans, w0: int, w1: int):
-    """Yield ``(span name, ns)`` for each idle gap inside [w0, w1).
+def _innermost(host_spans, w0: int, w1: int) -> list[tuple[int, int, str]]:
+    """Pieces ``(start, end, owner)`` that tile [w0, w1), each owned by the
+    innermost host span open over it, or "no span".  Spans come from
+    ``with`` blocks on one thread, so they nest, and a stack swept in start
+    order holds exactly the open ones."""
+    spans = sorted(
+        (s, -e, n[len("span."):]) for n, s, e in host_spans if n != WINDOW_SPAN
+    )
+    pieces: list[tuple[int, int, str]] = []
+    stack: list[tuple[int, str]] = []
+    cursor = w0
 
-    The owner is the innermost host span open at the gap's midpoint.  Spans
-    come from ``with`` blocks on one thread, so they nest, and a stack swept
-    in start order holds exactly the open ones.
-    """
+    def emit(upto: int) -> None:
+        nonlocal cursor
+        upto = min(upto, w1)
+        if upto > cursor:
+            pieces.append((cursor, upto, stack[-1][1] if stack else "no span"))
+            cursor = upto
+
+    for s, neg_e, name in spans:
+        while stack and stack[-1][0] <= s:
+            emit(stack[-1][0])
+            stack.pop()
+        emit(s)
+        stack.append((-neg_e, name))
+    while stack:
+        emit(stack[-1][0])
+        stack.pop()
+    emit(w1)
+    return pieces
+
+
+def _attributed_gaps(busy, host_spans, w0: int, w1: int):
+    """Yield ``(span name, ns)`` for each part of each idle gap inside
+    [w0, w1): a gap is split among the innermost spans open over it."""
     gaps, cursor = [], w0
     for s, e in list(busy) + [(w1, w1)]:
         if s > cursor:
-            gaps.append(((cursor + s) // 2, s - cursor))
+            gaps.append((cursor, s))
         cursor = max(cursor, e)
-    spans = sorted(
-        (s, e, n[len("span."):]) for n, s, e in host_spans if n != WINDOW_SPAN
-    )
-    stack: list[tuple[int, int, str]] = []
+    pieces = _innermost(host_spans, w0, w1)
     j = 0
-    for mid, length in gaps:
-        while j < len(spans) and spans[j][0] <= mid:
-            while stack and stack[-1][1] <= spans[j][0]:
-                stack.pop()
-            stack.append(spans[j])
+    for gs, ge in gaps:
+        while j < len(pieces) and pieces[j][1] <= gs:
             j += 1
-        while stack and stack[-1][1] <= mid:
-            stack.pop()
-        yield (stack[-1][2] if stack else "no span"), length
+        k = j
+        while k < len(pieces) and pieces[k][0] < ge:
+            ps, pe, owner = pieces[k]
+            yield owner, min(ge, pe) - max(gs, ps)
+            k += 1
 
 
 def reduce_profile(pd, *, top: int = 10) -> TraceSummary:
@@ -129,6 +158,8 @@ def reduce_profile(pd, *, top: int = 10) -> TraceSummary:
     kernel_ns: dict[str, int] = collections.Counter()
     kernel_calls: dict[str, int] = collections.Counter()
     op_ns: dict[str, int] = collections.Counter()
+    module_ns: dict[str, int] = collections.Counter()
+    module_calls: dict[str, int] = collections.Counter()
     first_busy: list[tuple[int, int]] = []
     for k, plane in enumerate(sorted(devices, key=lambda p: p.name)):
         lines = {line.name: line for line in plane.lines}
@@ -136,6 +167,10 @@ def reduce_profile(pd, *, top: int = 10) -> TraceSummary:
             (s, e, module_name(n)) for n, s, e in _events(lines["XLA Modules"])
         ) if "XLA Modules" in lines else []
         starts = [m[0] for m in modules]
+        for s, e, mod in modules:
+            if min(e, w1) > max(s, w0):
+                module_ns[mod] += min(e, w1) - max(s, w0)
+                module_calls[mod] += 1
         busy = []
         for name, s, e in _events(lines["XLA Ops"]) if "XLA Ops" in lines else ():
             s, e = max(s, w0), min(e, w1)
@@ -161,6 +196,8 @@ def reduce_profile(pd, *, top: int = 10) -> TraceSummary:
         n_devices=n_dev,
         kernel_s={k: v * 1e-9 for k, v in kernel_ns.items()},
         kernel_calls=dict(kernel_calls),
+        module_s={k: v * 1e-9 for k, v in module_ns.items()},
+        module_calls=dict(module_calls),
         top_ops=[(k, v * 1e-9) for k, v in sorted(op_ns.items(), key=lambda t: -t[1])[:top]],
         idle_by_host=[(k, v * 1e-9) for k, v in sorted(idle.items(), key=lambda t: -t[1])[:top]],
     )
