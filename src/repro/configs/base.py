@@ -29,13 +29,17 @@ class ArchConfig:
     head_dim: Optional[int] = None    # defaults to d_model // n_heads
 
     # Block structure -------------------------------------------------------
-    block_kind: str = "attn"          # attn | mamba2 | rwkv6
+    block_kind: str = "attn"          # attn | mamba2 | rwkv6 | lfm2
     # sliding-window pattern: (n_local, n_global) repeating, e.g. gemma3 (5,1)
     swa_pattern: Optional[Tuple[int, int]] = None
     window: int = 1024
     # hybrid (zamba2): shared attention block applied every `attn_every` ssm
     # blocks; 0 disables.
     attn_every: int = 0
+    # per-layer operator pattern (lfm2: "conv" | "full_attention" per layer,
+    # in the published ``layer_types`` order); None = a uniform stack
+    layer_types: Optional[Tuple[str, ...]] = None
+    n_dense_layers: int = 0           # leading layers with a dense FFN (lfm2)
 
     # MoE --------------------------------------------------------------------
     n_experts: int = 0
@@ -43,6 +47,9 @@ class ArchConfig:
     n_shared_experts: int = 0
     expert_d_ff: int = 0
     capacity_factor: float = 1.25
+    # (first, count): the routed experts this device holds of each MoE layer
+    # under expert parallelism; None = all of them
+    expert_share: Optional[Tuple[int, int]] = None
     moe_chunk: int = 512              # seq-chunk for einsum dispatch
 
     # SSM ---------------------------------------------------------------------

@@ -3,7 +3,17 @@
 All clients' data is pre-stacked into fixed-shape arrays (padding by cycling
 samples) so one ``vmap(local_sgd)`` call trains every sampled client of a
 round — the CPU-friendly *and* TPU-friendly formulation (the client axis maps
-onto the mesh data axis in ``launch/fl_train.py``).
+onto the mesh data axis in ``launch/fl_train.py``).  A model too large for
+one copy per sampled client trains them in sequence instead
+(``Strategy._run_sequential``).
+
+Two kinds of client data: rows of float features with integer labels
+(classification, :func:`ce_loss`), and integer token sequences (a client's
+``x`` is ``(n_sequences, seq_len)`` ids, its labels unused), trained on the
+next-token loss (:func:`next_token_loss`).  A token model's ``apply_fn``
+returns ``(h, head, stats)``: final hidden states, the output head
+(``logits = h @ head.T``) and counts the model keeps (a MoE layer's expert
+loads), summed over a local update's steps.
 """
 from __future__ import annotations
 
@@ -18,6 +28,11 @@ import numpy as np
 from repro.fl.partition import ClientData
 
 PyTree = Any
+
+# Width of a token client's data matrix for the ``svd`` signature family:
+# each sequence is a row of token counts folded into this many buckets by
+# ``id mod TOKEN_BUCKETS`` (the feature width of MIX-4's 32x32x3 rows).
+TOKEN_BUCKETS = 3072
 
 
 @dataclass
@@ -36,15 +51,36 @@ class StackedClients:
     def n_clients(self) -> int:
         return self.x.shape[0]
 
+    @property
+    def tokens(self) -> bool:
+        """Whether clients hold token sequences (next-token loss)."""
+        return is_tokens(self.x)
+
+
+def is_tokens(x) -> bool:
+    return np.issubdtype(x.dtype, np.integer)
+
+
+def feature_matrix(x: np.ndarray) -> np.ndarray:
+    """A client's (features, samples) data matrix for the ``svd`` signature
+    family: its rows transposed, or for token sequences each sequence's
+    token counts folded into :data:`TOKEN_BUCKETS` buckets."""
+    x = np.asarray(x)
+    if not is_tokens(x):
+        return x.T
+    counts = [np.bincount(row % TOKEN_BUCKETS, minlength=TOKEN_BUCKETS) for row in x]
+    return np.stack(counts, axis=1).astype(np.float32)
+
 
 def stack_clients(clients: list[ClientData]) -> StackedClients:
     K = len(clients)
     n_max = max(c.x_train.shape[0] for c in clients)
     t_max = max(c.x_test.shape[0] for c in clients)
     d = clients[0].x_train.shape[1]
-    x = np.zeros((K, n_max, d), np.float32)
+    xdt = np.int32 if is_tokens(clients[0].x_train) else np.float32
+    x = np.zeros((K, n_max, d), xdt)
     y = np.zeros((K, n_max), np.int64)
-    xt = np.zeros((K, t_max, d), np.float32)
+    xt = np.zeros((K, t_max, d), xdt)
     yt = np.zeros((K, t_max), np.int64)
     n = np.zeros((K,), np.int64)
     t = np.zeros((K,), np.int64)
@@ -78,6 +114,39 @@ def ce_loss(
     return jnp.sum(per_example * mask) / jnp.maximum(jnp.sum(mask), 1.0)
 
 
+# Positions per block of the next-token loss: the (B, block, V) logits of
+# one block live at a time, recomputed in the backward pass.
+LOSS_BLOCK = 1024
+
+
+def next_token_loss(apply_fn: Callable, params: PyTree, tokens: jax.Array):
+    """Mean next-token cross-entropy of token sequences (B, S) over their
+    B * (S - 1) predictions, and the model's counts.  A token model's
+    ``apply_fn(params, tokens)`` gives ``(h, head, stats)`` with logits
+    ``h @ head.T``; they are formed in blocks of :data:`LOSS_BLOCK`
+    positions."""
+    h, head, stats = apply_fn(params, tokens)
+    B, S, D = h.shape
+    n = S - 1
+    blk = min(LOSS_BLOCK, n)
+    pad = (-n) % blk
+    hp = jnp.pad(h[:, :-1], ((0, 0), (0, pad), (0, 0)))
+    tgt = jnp.pad(tokens[:, 1:], ((0, 0), (0, pad)))
+    valid = jnp.arange(n + pad) < n
+    nb = (n + pad) // blk
+    split = lambda a: jnp.moveaxis(a.reshape((B, nb, blk) + a.shape[2:]), 1, 0)
+
+    @jax.checkpoint
+    def block(args):
+        hb, tb, vb = args
+        logits = (hb @ head.T).astype(jnp.float32)
+        gold = jnp.take_along_axis(logits, tb[..., None], axis=-1)[..., 0]
+        return jnp.sum((jax.nn.logsumexp(logits, axis=-1) - gold) * vb)
+
+    total = jnp.sum(jax.lax.map(block, (split(hp), split(tgt), split(jnp.broadcast_to(valid, (B, n + pad))))))
+    return total / (B * n), stats
+
+
 def make_local_sgd(
     apply_fn: Callable,
     *,
@@ -87,41 +156,53 @@ def make_local_sgd(
     momentum: float = 0.5,
     prox_mu: float = 0.0,
     use_control_variates: bool = False,
+    with_stats: bool = False,
 ):
     """Build local_sgd(params, x, y, n, key, anchor, c_diff) -> new_params.
 
     * ``anchor``   — global params theta_g (FedProx proximal term); pass params
                      when unused.
     * ``c_diff``   — SCAFFOLD drift correction (c - c_k); zeros when unused.
-    Returns plain SGD with heavy-ball momentum (paper setup).
+    Returns plain SGD with heavy-ball momentum (paper setup); with
+    ``momentum=0`` plain SGD, which keeps no momentum buffer.  Token data
+    trains on :func:`next_token_loss`; ``with_stats`` then also returns the
+    model's counts summed over the steps.
     """
 
     def loss_fn(params, anchor, xb, yb):
-        l = ce_loss(apply_fn, params, xb, yb)
+        if jnp.issubdtype(xb.dtype, jnp.integer):
+            l, stats = next_token_loss(apply_fn, params, xb)
+        else:
+            l, stats = ce_loss(apply_fn, params, xb, yb), {}
         if prox_mu > 0.0:
             sq = sum(
                 jnp.sum(jnp.square(p - a))
                 for p, a in zip(jax.tree.leaves(params), jax.tree.leaves(anchor))
             )
             l = l + 0.5 * prox_mu * sq
-        return l
+        return l, stats
 
     def local_sgd(params, x, y, n, key, anchor, c_diff):
-        mu0 = jax.tree.map(jnp.zeros_like, params)
+        mu0 = jax.tree.map(jnp.zeros_like, params) if momentum else None
 
         def step(carry, key_t):
             params, mu = carry
             idx = jax.random.randint(key_t, (batch_size,), 0, jnp.maximum(n, 1))
             xb, yb = x[idx], y[idx]
-            g = jax.grad(loss_fn)(params, anchor, xb, yb)
+            g, stats = jax.grad(loss_fn, has_aux=True)(params, anchor, xb, yb)
             if use_control_variates:
                 g = jax.tree.map(lambda gi, ci: gi + ci, g, c_diff)
-            mu = jax.tree.map(lambda m, gi: momentum * m + gi, mu, g)
-            params = jax.tree.map(lambda p, m: p - lr * m, params, mu)
-            return (params, mu), None
+            if momentum:
+                mu = jax.tree.map(lambda m, gi: momentum * m + gi, mu, g)
+                params = jax.tree.map(lambda p, m: p - lr * m, params, mu)
+            else:
+                params = jax.tree.map(lambda p, gi: p - lr * gi, params, g)
+            return (params, mu), stats
 
         keys = jax.random.split(key, steps)
-        (params, _), _ = jax.lax.scan(step, (params, mu0), keys)
+        (params, _), stats = jax.lax.scan(step, (params, mu0), keys)
+        if with_stats:
+            return params, jax.tree.map(lambda s: jnp.sum(s, axis=0), stats)
         return params
 
     return local_sgd
@@ -163,6 +244,23 @@ def batch_eval(apply_fn, stacked_params, xt, yt, t):
         return jnp.sum((pred == y) * mask) / jnp.maximum(tk, 1)
 
     return jax.vmap(one)(stacked_params, xt, yt, t)
+
+
+@functools.partial(jax.jit, static_argnames=("apply_fn",))
+def token_eval(apply_fn, cluster_params, labels, xt, t):
+    """Per-client next-token top-1 accuracy, each client under its cluster's
+    model, one client after another (no per-client model copies)."""
+
+    def one(args):
+        z, x, tk = args
+        params = jax.tree.map(lambda l: l[z], cluster_params)
+        h, head, _ = apply_fn(params, x)
+        logits = h @ head.T
+        hit = jnp.argmax(logits[:, :-1], axis=-1) == x[:, 1:]
+        mask = jnp.arange(x.shape[0]) < tk
+        return jnp.sum(jnp.mean(hit, axis=-1) * mask) / jnp.maximum(tk, 1)
+
+    return jax.lax.map(one, (labels, xt, t))
 
 
 def weighted_average(stacked: PyTree, weights: jax.Array) -> PyTree:

@@ -11,6 +11,8 @@ stacked-clients representation.  Communication bytes are tracked per round
 from __future__ import annotations
 
 import dataclasses
+import functools
+import os
 import re
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
@@ -19,14 +21,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core.pacfl import PACFLConfig, cluster_clients, compute_signatures
 from repro.core.signatures import FamilyContext, get_family, payloads_from_stacked
 from repro.fl.client import (
     StackedClients,
     batch_eval,
     ce_loss,
+    feature_matrix,
     make_local_sgd,
     make_perfedavg_local,
+    token_eval,
     tree_size_bytes,
     weighted_average,
 )
@@ -66,6 +71,43 @@ def _broadcast(tree: PyTree, m: int) -> PyTree:
 
 def _zeros_like_stack(tree: PyTree, m: int) -> PyTree:
     return jax.tree.map(lambda l: jnp.zeros((m,) + l.shape, l.dtype), tree)
+
+
+def device_memory_bytes() -> int:
+    """Memory of the device the round runs on: the accelerator's own limit,
+    or the host's RAM where the backend reports none (the CPU)."""
+    stats = jax.devices()[0].memory_stats() or {}
+    if "bytes_limit" in stats:
+        return int(stats["bytes_limit"])
+    return os.sysconf("SC_PAGE_SIZE") * os.sysconf("SC_PHYS_PAGES")
+
+
+def vmapped_fits(model_bytes: int, m: int, n_models: int, device_bytes: int) -> bool:
+    """Whether one local-update copy per sampled client fits: the ``m``
+    trained copies, their gradients and momentum (3 m models) beside the
+    ``n_models`` the strategy holds, in half the device's memory (the rest
+    is activations)."""
+    return (3 * m + n_models) * model_bytes <= device_bytes // 2
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _fold_client(acc, trained, w, fresh):
+    """``acc <- (0 if fresh else acc) + w * trained``, in place.  Kept apart
+    from the client's update: fused into it, XLA holds two more model-sized
+    buffers (16.6 against 14.5 GB for LFM2's one-chip share on a v5e)."""
+    return jax.tree.map(lambda a, p: jnp.where(fresh, 0.0, a) + w * p, acc, trained)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _write_mean(cluster_params, acc, wsum, z):
+    """Cluster ``z``'s model becomes the running weighted sum over its
+    weight: the per-cluster weighted mean, in place."""
+    return jax.tree.map(lambda all_, a: all_.at[z].set(a / wsum), cluster_params, acc)
+
+
+@jax.jit
+def _zeros_one(cluster_params):
+    return jax.tree.map(lambda l: jnp.zeros(l.shape[1:], l.dtype), cluster_params)
 
 
 def bucket_steps(steps: int) -> int:
@@ -153,8 +195,10 @@ class Strategy:
         self._set_steps(self._steps_exact)
         self.data = data
         self._P = None  # model bytes, set after init
+        self._device_bytes = device_memory_bytes()
+        self._seq_cache: dict[int, Callable] = {}
 
-    def _make_local(self, steps: int) -> Callable:
+    def _make_local(self, steps: int, **kw) -> Callable:
         """Local-update factory for a given step count (Per-FedAvg overrides)."""
         return make_local_sgd(
             self.apply_fn,
@@ -164,6 +208,7 @@ class Strategy:
             momentum=self.cfg.momentum,
             prox_mu=self._prox_mu,
             use_control_variates=self._use_cv,
+            **kw,
         )
 
     def _set_steps(self, steps: int) -> None:
@@ -207,6 +252,54 @@ class Strategy:
         if c_diffs is None:
             c_diffs = _zeros_like_stack(jax.tree.map(lambda l: l[0], stacked_params), m)
         return self._vupdate(stacked_params, x, y, n, keys, anchors, c_diffs)
+
+    def _client_update(self) -> Callable:
+        """The jitted update of one client from its model in a stack:
+        ``(stack, x, y, n, key, z) -> (trained, stats)``, one program for
+        every client of the round's shape (``jit_client_update``)."""
+        fn = self._seq_cache.get(self._steps)
+        if fn is None:
+            local = self._make_local(self._steps, with_stats=True)
+
+            def client_update(stack, x, y, n, key, z):
+                start = jax.tree.map(lambda l: l[z], stack)
+                return local(start, x, y, n, key, start, start)
+
+            fn = self._seq_cache[self._steps] = jax.jit(client_update)
+        return fn
+
+    def _run_sequential(self, stack, pick, sampled, key):
+        """A round's local updates one client after another, each from model
+        ``pick[i]`` of ``stack``, folded into a running weighted sum per
+        touched model that replaces it once its last client is in; models no
+        sampled client picked keep theirs bit for bit.  The clients keep
+        their sample-order keys (``split(key, m)[i]``) and run grouped by
+        model.  Used where one model copy per sampled client does not fit
+        (:func:`vmapped_fits`).  Returns the new stack and the model's
+        counts summed over the round's clients and steps."""
+        keys = jax.random.split(key, len(sampled))
+        order = np.argsort(pick, kind="stable")
+        update = self._client_update()
+        seq_len = int(self.data.x.shape[-1])
+        acc, stats = _zeros_one(stack), []
+        for j, i in enumerate(order):
+            k, z = int(sampled[i]), int(pick[i])
+            w = float(self.data.n[k])
+            fresh = j == 0 or pick[order[j - 1]] != z
+            wsum = w if fresh else wsum + w
+            with tracing.span("lmround.local") as sp:
+                sp.count("tokens", int(self.data.n[k]) * seq_len)
+                trained, st = update(
+                    stack, jnp.asarray(self.data.x[k]), jnp.asarray(self.data.y[k]),
+                    jnp.int32(self.data.n[k]), keys[i], jnp.int32(z),
+                )
+                acc = _fold_client(acc, trained, jnp.float32(w), fresh)
+                del trained
+            stats.append(st)
+            if j == len(order) - 1 or pick[order[j + 1]] != z:
+                with tracing.span("lmround.aggregate"):
+                    stack = _write_mean(stack, acc, jnp.float32(wsum), jnp.int32(z))
+        return stack, jax.tree.map(lambda *s: sum(s), *stats)
 
     def evaluate(self) -> np.ndarray:
         params = self.eval_params()
@@ -645,7 +738,8 @@ class PACFL(Strategy):
     def _client_mats(data):
         """(features, samples) data matrices, one per stacked client."""
         return [
-            jnp.asarray(data.x[k, : data.n[k]].T) for k in range(data.n_clients)
+            jnp.asarray(feature_matrix(data.x[k, : data.n[k]]))
+            for k in range(data.n_clients)
         ]
 
     def _family_payloads(self, data):
@@ -670,7 +764,7 @@ class PACFL(Strategy):
             key = jax.random.fold_in(self._key, 1_000_003 + self._sig_seq)
             self._sig_seq += 1
             payload = (
-                jnp.asarray(client.x_train.T)
+                jnp.asarray(feature_matrix(client.x_train))
                 if self.cfg.pacfl.family == "svd" else client
             )
             return self._family.signature_one(
@@ -715,7 +809,7 @@ class PACFL(Strategy):
             U_ref = getattr(batch, "refresh_signatures", None)
             if U_ref is None:
                 payloads = (
-                    [jnp.asarray(c.x_train.T) for c in batch.refresh_clients]
+                    [jnp.asarray(feature_matrix(c.x_train)) for c in batch.refresh_clients]
                     if self.cfg.pacfl.family == "svd"
                     else list(batch.refresh_clients)
                 )
@@ -738,7 +832,7 @@ class PACFL(Strategy):
                 # data reflects the whole drain, so its trailing rows are
                 # NOT this batch's newcomers when a drain splits batches
                 payloads = (
-                    [jnp.asarray(c.x_train.T) for c in batch.join]
+                    [jnp.asarray(feature_matrix(c.x_train)) for c in batch.join]
                     if self.cfg.pacfl.family == "svd" else list(batch.join)
                 )
                 U_new = compute_signatures(
@@ -774,23 +868,48 @@ class PACFL(Strategy):
 
     def run_round(self, rnd, sampled, key):
         m = len(sampled)
-        P = self._model_bytes(jax.tree.map(lambda l: l[0], self.cluster_params))
+        # from the shapes: slicing a model out of the stack would copy it
+        P = self._model_bytes(jax.tree.map(
+            lambda l: jax.ShapeDtypeStruct(l.shape[1:], l.dtype), self.cluster_params))
         pick = self.labels[sampled]
-        stacked = _take(self.cluster_params, pick)
-        new = self._run_local(stacked, sampled, key)
-        w = jnp.asarray(self.data.n[sampled], jnp.float32)
-        for z in np.unique(pick):
-            mask = pick == z
-            idx = np.where(mask)[0]
-            avg = weighted_average(_take(new, idx), w[np.asarray(idx)])
-            self.cluster_params = jax.tree.map(
-                lambda all_, a: all_.at[int(z)].set(a), self.cluster_params, avg
-            )
+        Z = jax.tree.leaves(self.cluster_params)[0].shape[0]
+        if vmapped_fits(P, m, Z, self._device_bytes):
+            stacked = _take(self.cluster_params, pick)
+            new = self._run_local(stacked, sampled, key)
+            w = jnp.asarray(self.data.n[sampled], jnp.float32)
+            for z in np.unique(pick):
+                mask = pick == z
+                idx = np.where(mask)[0]
+                avg = weighted_average(_take(new, idx), w[np.asarray(idx)])
+                self.cluster_params = jax.tree.map(
+                    lambda all_, a: all_.at[int(z)].set(a), self.cluster_params, avg
+                )
+        else:
+            with tracing.span("lmround") as sp:
+                sp.count("clients", m)
+                self.cluster_params, self.round_stats = self._run_sequential(
+                    self.cluster_params, pick, sampled, key
+                )
+                load = self.round_stats.get("expert_load")
+                if load is not None and load.size and tracing.enabled():
+                    load = np.asarray(load)   # read once a round, only while traced
+                    sp.count("expert_load_max", load.max(axis=1).sum())
+                    sp.count("expert_load_sum", load.sum())
+                    sp.count("experts_held", load.shape[1])
         self.comm_down += P * m   # each client downloads only ITS cluster model
         self.comm_up += P * m
 
     def eval_params(self):
         return _take(self.cluster_params, self.labels)
+
+    def evaluate(self) -> np.ndarray:
+        if not self.data.tokens:
+            return super().evaluate()
+        acc = token_eval(
+            self.apply_fn, self.cluster_params, jnp.asarray(self.labels),
+            jnp.asarray(self.data.x_test), jnp.asarray(self.data.t),
+        )
+        return np.asarray(acc)
 
 
 STRATEGIES: dict[str, type] = {

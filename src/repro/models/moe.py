@@ -110,3 +110,102 @@ def moe_apply(params: dict, x: jax.Array, cfg: ArchConfig) -> tuple[jax.Array, j
     if "shared" in params:
         out = out + mlp_apply(params["shared"], x, cfg.act)
     return out, aux / nc
+
+
+# ---------------------------------------------------------------------------
+# Held-expert MoE (expert parallelism's share on one device), dropless
+# ---------------------------------------------------------------------------
+#
+# The router scores all ``n_experts``; this device holds the experts
+# ``cfg.expert_share = (first, count)`` and computes only their part of the
+# result, for every (token, choice) pair routed to them: no capacity, no
+# dropped pair.  The pairs are sorted by expert and run through grouped
+# matrix products (``jax.lax.ragged_dot``) over the held experts; pairs of
+# experts held elsewhere sort past the last group and are not computed.
+
+# Spread of the seeded selection-only expert bias (a pre-trained model's is
+# learnt outside the gradient; a random one has to be drawn).
+EXPERT_BIAS_STD = 0.05
+
+
+def expert_share(cfg: ArchConfig) -> tuple[int, int]:
+    return cfg.expert_share or (0, cfg.n_experts)
+
+
+def init_held_moe(key: jax.Array, cfg: ArchConfig) -> dict:
+    """Router over all experts, the selection bias, and the held experts'
+    SwiGLU weights ``w1``, ``w3`` (E_h, D, F) and ``w2`` (E_h, F, D).  Expert
+    ``e`` is drawn from its own key, so the shares of one key compose into
+    the whole layer."""
+    d, f = cfg.d_model, cfg.expert_d_ff
+    first, held = expert_share(cfg)
+    kr, kb, kw = jax.random.split(key, 3)
+
+    def expert(k):
+        k1, k3, k2 = jax.random.split(k, 3)
+        return {
+            "w1": dense_init(k1, d, f),
+            "w3": dense_init(k3, d, f),
+            "w2": dense_init(k2, f, d),
+        }
+
+    keys = jax.random.split(kw, cfg.n_experts)[first : first + held]
+    return {
+        "router": dense_init(kr, d, cfg.n_experts),
+        "expert_bias": EXPERT_BIAS_STD * jax.random.normal(kb, (cfg.n_experts,), jnp.float32),
+        **jax.vmap(expert)(keys),
+    }
+
+
+def route(params: dict, h: jax.Array, cfg: ArchConfig) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """(T, D) -> sigmoid scores (T, E), the selected experts (T, k) and
+    their weights (T, k).  The bias only selects; the weights are the
+    unbiased scores of the selection, renormalised."""
+    scores = jax.nn.sigmoid(jnp.dot(h, params["router"]).astype(jnp.float32))
+    biased = scores + jax.lax.stop_gradient(params["expert_bias"])
+    _, sel = jax.lax.top_k(biased, cfg.top_k)
+    w = jnp.take_along_axis(scores, sel, axis=-1)
+    return scores, sel, w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+
+
+def dispatch_slots(sel: jax.Array, first: int, held: int) -> jax.Array:
+    """The held expert each (token, choice) pair goes to, ``held`` (past the
+    last group) for a pair whose expert another device holds."""
+    local = sel - first
+    return jnp.where((local >= 0) & (local < held), local, held)
+
+
+def held_moe_apply(params: dict, h: jax.Array, cfg: ArchConfig):
+    """The held experts' part of the MoE output for tokens ``h`` (T, D).
+
+    Returns ``(out, stats)``: ``stats["expert_load"]`` (E_h,) counts the
+    pairs sorted into each held expert's group, ``stats["routed"]`` the
+    pairs the router sent to held experts, and ``stats["computed"]`` the
+    rows that reach the output with a nonzero product, counted from the
+    products' output itself, not from the groups: equal to ``routed`` where
+    nothing is dropped.  Rows past the last group are masked before they
+    are counted: on a TPU the grouped products leave them unwritten, and
+    what they hold is not a result."""
+    first, held = expert_share(cfg)
+    k = cfg.top_k
+    _, sel, w = route(params, h, cfg)
+    slot = dispatch_slots(sel, first, held).reshape(-1)            # (T*k,)
+    order = jnp.argsort(slot, stable=True)
+    slot_sorted = slot[order]
+    group_sizes = jnp.bincount(slot, length=held + 1)[:held].astype(jnp.int32)
+    tok = order // k
+    # Rows past the last group are left uncomputed by the grouped products,
+    # forward and backward (on a TPU they hold whatever the buffer held):
+    # select them away before any multiply, so that no value of theirs
+    # reaches the output, ``h``'s gradient or the router's.
+    valid = (slot_sorted < held)[:, None]
+    xs = jnp.where(valid, h[tok], 0.0)
+    a = jax.lax.ragged_dot(xs, params["w1"], group_sizes, preferred_element_type=jnp.float32)
+    b = jax.lax.ragged_dot(xs, params["w3"], group_sizes, preferred_element_type=jnp.float32)
+    y = jax.lax.ragged_dot(jax.nn.silu(a) * b, params["w2"], group_sizes,
+                           preferred_element_type=jnp.float32)
+    y = jnp.where(valid, y, 0.0) * w.reshape(-1)[order][:, None]
+    computed = jnp.sum(jnp.any(y != 0, axis=-1)).astype(jnp.int32)
+    out = jnp.zeros(h.shape, jnp.float32).at[tok].add(y)
+    routed = jnp.sum((sel >= first) & (sel < first + held)).astype(jnp.int32)
+    return out.astype(h.dtype), {"expert_load": group_sizes, "routed": routed, "computed": computed}

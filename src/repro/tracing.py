@@ -138,6 +138,11 @@ def span(name: str):
     return _Span(name)
 
 
+def enabled() -> bool:
+    """Whether spans record now (a profiler session is active)."""
+    return TraceAnnotation.is_enabled()
+
+
 def records() -> list[SpanRecord]:
     """The closed spans held in the ring, oldest first."""
     with _ring_lock:

@@ -277,3 +277,44 @@ def test_profiler_trace_holds_every_record_on_one_clock(traced):
             ps, pe = matched[r.parent]
             s, e = matched[r.id]
             assert ps <= s and e <= pe, r.name
+
+
+def test_sequential_round_spans(tmp_path, monkeypatch):
+    """A PACFL round on its sequential path records one ``lmround`` span with
+    the round's expert-load counts, a ``lmround.local`` child per sampled
+    client counting its tokens, and a ``lmround.aggregate`` child per
+    touched cluster."""
+    import functools
+
+    from test_lfm2 import _arch, _clients, _fl
+
+    from repro.fl import strategies
+    from repro.fl.client import stack_clients
+    from repro.models import lfm2
+
+    monkeypatch.setattr(strategies, "vmapped_fits", lambda *a: False)
+    cfg = _arch()
+    data = stack_clients(_clients())
+    strat = strategies.PACFL(functools.partial(lfm2.apply, cfg=cfg),
+                             functools.partial(lfm2.init_params, cfg=cfg), _fl())
+    strat.setup(jax.random.PRNGKey(0), data)
+    sampled = np.array([0, 2, 3, 4])
+    strat.run_round(0, sampled, jax.random.PRNGKey(1))   # compile outside the trace
+    tracing.reset()
+    with jax.profiler.trace(str(tmp_path)):
+        strat.run_round(1, sampled, jax.random.PRNGKey(2))
+    by = _by_name(tracing.records())
+    tracing.reset()
+    (top,) = by["lmround"]
+    assert top.parent is None
+    local, agg = by["lmround.local"], by["lmround.aggregate"]
+    assert len(local) == 4 and len(agg) == 2
+    assert all(r.parent == top.id for r in local + agg)
+    assert [r.counts["tokens"] for r in local] == [int(data.n[k]) * data.x.shape[-1] for k in (0, 2, 4, 3)]
+    load = np.asarray(strat.round_stats["expert_load"])
+    assert top.counts == {
+        "clients": 4,
+        "expert_load_max": int(load.max(axis=1).sum()),
+        "expert_load_sum": int(load.sum()),
+        "experts_held": 2,
+    }
