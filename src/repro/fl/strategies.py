@@ -105,6 +105,34 @@ def _write_mean(cluster_params, acc, wsum, z):
     return jax.tree.map(lambda all_, a: all_.at[z].set(a / wsum), cluster_params, acc)
 
 
+def _cluster_means_program() -> Callable:
+    """A new jitted ``(cluster_params, new, pick, w) -> cluster_params``: the
+    vmapped round's per-cluster FedAvg as one program.  Row ``z`` of the
+    (Z, m) weights is ``w[i]`` where client ``i`` picked model ``z`` and 0
+    elsewhere; each row goes through :func:`weighted_average` (a normalised
+    ``tensordot``, here at ``HIGHEST``), and models no sampled client picked
+    keep theirs bit for bit.  Its shapes are (Z, m) whatever the sample's
+    clusters, so it compiles once per (m, Z).  Made anew per strategy, so
+    each traces the module's ``weighted_average`` as it stands then."""
+
+    def cluster_means(cluster_params, new, pick, w):
+        Z = jax.tree.leaves(cluster_params)[0].shape[0]
+        member = pick[None, :] == jnp.arange(Z, dtype=pick.dtype)[:, None]
+        with jax.default_matmul_precision("highest"):
+            means = jax.vmap(weighted_average, in_axes=(None, 0))(
+                new, jnp.where(member, w[None, :], 0.0)
+            )
+        touched = member.any(axis=1)
+        return jax.tree.map(
+            lambda old, mean: jnp.where(
+                touched.reshape((Z,) + (1,) * (old.ndim - 1)), mean, old
+            ),
+            cluster_params, means,
+        )
+
+    return jax.jit(cluster_means)
+
+
 @jax.jit
 def _zeros_one(cluster_params):
     return jax.tree.map(lambda l: jnp.zeros(l.shape[1:], l.dtype), cluster_params)
@@ -706,6 +734,10 @@ class PACFL(Strategy):
 
     def setup(self, key, data):
         self._build(data)
+        # the vmapped round's gather and aggregation: one dispatch each, with
+        # shapes that do not depend on how the sample falls into clusters
+        self._gather = jax.jit(_take)
+        self._cluster_means = _cluster_means_program()
         self._key = key
         self._sig_seq = 0   # deterministic key stream for eager signatures
         # One-shot phase: clients compute + upload their signatures through
@@ -874,16 +906,13 @@ class PACFL(Strategy):
         pick = self.labels[sampled]
         Z = jax.tree.leaves(self.cluster_params)[0].shape[0]
         if vmapped_fits(P, m, Z, self._device_bytes):
-            stacked = _take(self.cluster_params, pick)
-            new = self._run_local(stacked, sampled, key)
+            pick_d = jnp.asarray(pick, jnp.int32)
+            new = self._run_local(self._gather(self.cluster_params, pick_d), sampled, key)
             w = jnp.asarray(self.data.n[sampled], jnp.float32)
-            for z in np.unique(pick):
-                mask = pick == z
-                idx = np.where(mask)[0]
-                avg = weighted_average(_take(new, idx), w[np.asarray(idx)])
-                self.cluster_params = jax.tree.map(
-                    lambda all_, a: all_.at[int(z)].set(a), self.cluster_params, avg
-                )
+            with tracing.span("pacfl.aggregate") as sp:
+                sp.count("clients", m)
+                sp.count("clusters_touched", np.unique(pick).size)
+                self.cluster_params = self._cluster_means(self.cluster_params, new, pick_d, w)
         else:
             with tracing.span("lmround") as sp:
                 sp.count("clients", m)

@@ -221,3 +221,110 @@ class TestChurn:
             run_federation("solo", clients[:10], mlp_clf_apply, init_fn,
                            cfg, seed=0,
                            churn=[ChurnEvent(rnd=2, leave=[0])])
+
+
+class TestVmappedAggregation:
+    """PACFL's vmapped round averages each touched cluster's local updates in
+    one compiled program: the float64 per-cluster weighted mean, models no
+    sampled client picked kept bit for bit, one compile per (m, Z)."""
+
+    D = 32
+
+    @classmethod
+    def _clients(cls, groups, seed=0):
+        """One client per entry of ``groups``, of 20 or 40 samples in turn,
+        whose data lies near plane ``g`` of four fixed random planes:
+        clients of one group share a subspace, clients of two are far
+        apart."""
+        from repro.fl.partition import ClientData
+
+        planes = np.random.default_rng(99).normal(size=(4, 2, cls.D))
+        rng = np.random.default_rng(seed)
+        out = []
+        for k, g in enumerate(groups):
+            n = (20, 40)[k % 2]
+            x = rng.normal(size=(n + 4, 2)) @ planes[g] + 0.05 * rng.normal(size=(n + 4, cls.D))
+            x = x.astype(np.float32)
+            y = rng.integers(0, 4, n + 4)
+            out.append(ClientData(x[:n], y[:n], x[n:], y[n:], f"g{g}"))
+        return out
+
+    @classmethod
+    def _strategy(cls, clients, sample_frac=0.34):
+        from repro.fl.client import stack_clients
+        from repro.fl.strategies import PACFL
+
+        cfg = FLConfig(sample_frac=sample_frac, local_epochs=1, batch_size=10, lr=0.05,
+                       pacfl=PACFLConfig(p=2, beta=45.0, measure="eq2"))
+        strat = PACFL(mlp_clf_apply, lambda key: init_mlp_clf(key, cls.D, 4, hidden=(16,)), cfg)
+        strat.setup(jax.random.PRNGKey(0), stack_clients(clients))
+        return strat
+
+    @staticmethod
+    def _round(strat, sampled, key):
+        """Run one round, returning the models before it and the sampled
+        clients' local updates it averaged."""
+        before, seen = strat.cluster_params, {}
+        real = strat._run_local
+
+        def keep(*a, **kw):
+            seen["new"] = real(*a, **kw)
+            return seen["new"]
+
+        strat._run_local = keep
+        try:
+            strat.run_round(1, sampled, key)
+        finally:
+            del strat._run_local
+        return before, seen["new"]
+
+    @staticmethod
+    def _check(strat, sampled, before, new):
+        pick = strat.labels[sampled]
+        w = strat.data.n[sampled].astype(np.float64)
+        for got, old, upd in zip(*(jax.tree.leaves(t) for t in (strat.cluster_params, before, new))):
+            for z in range(got.shape[0]):
+                idx = np.flatnonzero(pick == z)
+                if idx.size == 0:
+                    assert np.array_equal(np.asarray(got[z]), np.asarray(old[z]))
+                    continue
+                want = np.tensordot(w[idx] / w[idx].sum(), np.asarray(upd, np.float64)[idx], axes=(0, 0))
+                np.testing.assert_allclose(np.asarray(got[z], np.float64), want, rtol=1e-6, atol=1e-7)
+
+    def test_touched_clusters_get_the_float64_weighted_mean(self):
+        """Two of three clusters touched: their means against float64, the
+        third cluster's model bit for bit."""
+        strat = self._strategy(self._clients([0, 1, 2] * 4))
+        assert len(np.unique(strat.labels)) == 3
+        sampled = np.flatnonzero(strat.labels != strat.labels[0])[:4]
+        before, new = self._round(strat, sampled, jax.random.PRNGKey(3))
+        assert len(np.unique(strat.labels[sampled])) == 2
+        self._check(strat, sampled, before, new)
+
+    def test_one_compile_for_every_count_per_cluster(self):
+        strat = self._strategy(self._clients([0, 1] * 6))
+        m = 4
+        a = np.flatnonzero(strat.labels == strat.labels[0])
+        b = np.flatnonzero(strat.labels != strat.labels[0])
+        for cnt in range(1, m + 1):
+            sampled = np.sort(np.concatenate([a[:cnt], b[: m - cnt]]))
+            before, new = self._round(strat, sampled, jax.random.PRNGKey(cnt))
+            self._check(strat, sampled, before, new)
+        assert strat._cluster_means._cache_size() == 1
+
+    def test_round_after_churn_grows_the_stack(self):
+        from repro.fl.churn import ChurnBatch
+        from repro.fl.client import stack_clients
+
+        clients = self._clients([0, 1] * 6)
+        strat = self._strategy(clients)
+        strat.run_round(0, np.arange(4), jax.random.PRNGKey(5))
+        Z = jax.tree.leaves(strat.cluster_params)[0].shape[0]
+        joins = self._clients([3, 3], seed=1)
+        strat.handle_churn(stack_clients(clients + joins), ChurnBatch(join=joins))
+        assert jax.tree.leaves(strat.cluster_params)[0].shape[0] == Z + 1
+        sampled = np.array([0, 1, 12, 13])
+        assert strat.labels[12] == strat.labels[13] == Z
+        before, new = self._round(strat, sampled, jax.random.PRNGKey(6))
+        self._check(strat, sampled, before, new)
+        assert strat._cluster_means._cache_size() == 2

@@ -318,3 +318,24 @@ def test_sequential_round_spans(tmp_path, monkeypatch):
         "expert_load_sum": int(load.sum()),
         "experts_held": 2,
     }
+
+
+def test_vmapped_round_aggregate_span(tmp_path):
+    """A PACFL round on its vmapped path records one ``pacfl.aggregate`` span
+    around the per-cluster aggregation, counting the sampled clients and the
+    clusters they picked."""
+    from test_fl import TestVmappedAggregation
+
+    strat = TestVmappedAggregation._strategy(TestVmappedAggregation._clients([0, 1, 2] * 4))
+    sampled = np.array([0, 3, 4, 7])
+    strat.run_round(0, sampled, jax.random.PRNGKey(1))   # compile outside the trace
+    tracing.reset()
+    with jax.profiler.trace(str(tmp_path)):
+        strat.run_round(1, sampled, jax.random.PRNGKey(2))
+    by = _by_name(tracing.records())
+    tracing.reset()
+    assert set(by) == {"pacfl.aggregate"}
+    (agg,) = by["pacfl.aggregate"]
+    assert agg.parent is None
+    assert agg.counts == {"clients": 4, "clusters_touched": len(np.unique(strat.labels[sampled]))}
+    assert agg.counts["clusters_touched"] == 2
